@@ -86,6 +86,7 @@ from repro.core.baselines.engines import (
 from repro.core.baselines.io_model import IOParams, MODELS, io_table
 from repro.core.graph import from_edge_list, rmat_graph, small_world_graph
 from repro.core.vsw import VSWEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Tracer, trace
 
 GRAPH_V, GRAPH_E, SHARDS = 20_000, 400_000, 8
@@ -1294,6 +1295,7 @@ def main() -> None:
                          "export a Chrome-trace JSON (Perfetto-loadable) "
                          "to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = None
     if args.trace:

@@ -1,8 +1,9 @@
 """Kernel microbenchmarks: structure + CPU-reference timings.
 
-Pallas kernels run in interpret mode here (CPU container); wall times are
-NOT TPU numbers — they validate structure and give the jnp-path CPU
-baseline.  TPU perf is covered by the roofline analysis in EXPERIMENTS.md.
+Off a TPU the Pallas kernels run in the interpreter
+(``repro.kernels.pallas_compiled``); wall times from such a run are CPU
+numbers, not device metrics — they validate structure and give the
+jnp-path CPU baseline.
 """
 
 from __future__ import annotations
@@ -14,6 +15,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _t(fn, *args, reps=5):
@@ -196,6 +199,7 @@ def main() -> None:
                     help="merge rows into a persistent perf-trajectory "
                          "JSON (bench_graphmp format)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows: List[str] = []
     t0 = time.perf_counter()

@@ -12,6 +12,8 @@ import sys
 import time
 from typing import List
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def bench_train_throughput(rows: List[str]) -> None:
     """End-to-end smoke-scale training throughput (CPU, reduced configs)."""
@@ -40,6 +42,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     choices=[None, "graphmp", "kernels", "train"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows: List[str] = []
     t0 = time.time()

@@ -425,20 +425,18 @@ def make_superstep(
         n_active = jax.lax.psum(changed, axes)
         return new_local, n_active
 
-    from jax.experimental.shard_map import shard_map
-
     if sentinel:
         fn = lambda s, i, g, o: local_update(s, i, None, g, o)
         n_in = 4
     else:
         fn = local_update
         n_in = 5
-    step = shard_map(
+    step = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(vspec,) * n_in,
         out_specs=(vspec, P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     in_shardings = tuple(NamedSharding(mesh, s) for s in (vspec,) * n_in)

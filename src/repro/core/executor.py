@@ -26,8 +26,9 @@ numpy      ``np.add.at`` / ``np.minimum.at`` scatter-reduce over CSR — the
 jnp        windowed ELL gather + masked reduce + segment combine under
            ``jax.jit`` (shape-bucketed to bound recompiles) — what XLA
            would run.
-pallas     the ``repro.kernels.spmv_ell`` TPU kernel (interpret mode on
-           CPU) — the production hot loop.
+pallas     the ``repro.kernels.spmv_ell`` Pallas kernel — compiled on a
+           TPU, the Pallas interpreter elsewhere
+           (:func:`repro.kernels.pallas_compiled`).
 =========  ==================================================================
 """
 
@@ -379,8 +380,7 @@ def _ragged_stage_lanes(msgs_by_group, combines, n_pad_v: int):
     }
 
 
-def _ragged_dispatch_jnp(ells: List[EllShard], lane_ctx, *,
-                         interpret: bool = True):
+def _ragged_dispatch_jnp(ells: List[EllShard], lane_ctx):
     """Launch ONE jnp ragged update; the accumulator is left unforced so
     the caller can overlap the next batch's decode (double buffering)."""
     import jax.numpy as jnp
@@ -396,11 +396,10 @@ def _ragged_dispatch_jnp(ells: List[EllShard], lane_ctx, *,
     return batch, acc
 
 
-def _ragged_dispatch_pallas(ells: List[EllShard], lane_ctx, *,
-                            interpret: bool = True):
+def _ragged_dispatch_pallas(ells: List[EllShard], lane_ctx):
     from repro.kernels.spmv_ell import ops as spmv_ops
 
-    return spmv_ops.ragged_dispatch(ells, lane_ctx, interpret=interpret)
+    return spmv_ops.ragged_dispatch(ells, lane_ctx)
 
 
 def _ragged_collect(batch, acc, group_slices) -> List[List[np.ndarray]]:
@@ -813,7 +812,7 @@ class MeshLaneExecutor:
 
     def __init__(self, backend: str, partition, mesh=None, *,
                  batch_shards: int = 1, lanes: bool = False,
-                 interpret: bool = True, ragged: bool = True):
+                 ragged: bool = True):
         if backend not in LANE_BACKENDS:
             raise ValueError(
                 f"unknown backend {backend}; have {sorted(LANE_BACKENDS)}"
@@ -827,7 +826,6 @@ class MeshLaneExecutor:
         self.mesh = mesh
         self.batch_shards = batch_shards
         self.lanes = lanes
-        self.interpret = interpret
         #: RaggedFuse under the mesh: one shard_map step per flush covers
         #: every live group ("1 host read, 1 SPMD step, D slices"); the
         #: numpy emulation books the identical accounting.  Collection is
@@ -914,12 +912,11 @@ class MeshLaneExecutor:
                         lane_ctx = spmv_ops.mesh_ragged_stage_lanes(
                             [ga[0] for _, ga in live],
                             [ga[1] for _, ga in live],
-                            ell.num_windows * ell.window, n_dev,
+                            ell.num_windows * ell.window, self.mesh,
                         )
                     h = spmv_ops.mesh_ragged_dispatch(
                         [[ls.ell for ls in buf] for buf in bufs], lane_ctx,
                         mesh=self.mesh, backend=self.backend_name,
-                        interpret=self.interpret,
                     )
                     handle = ("mesh", h, list(bufs))
             if stats is not None:
@@ -1016,7 +1013,6 @@ class MeshLaneExecutor:
                     [ga[0] for _, ga in live],
                     [ga[1] for _, ga in live],
                     mesh=self.mesh, backend=self.backend_name,
-                    interpret=self.interpret,
                 )
                 for (gi, _), accs_dev in zip(live, accs_by_group):
                     for buf, accs in zip(bufs, accs_dev):

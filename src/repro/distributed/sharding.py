@@ -81,7 +81,7 @@ class ShardingCtx:
         if self.mesh is None or self.rules is None:
             return x
         return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, self.spec(*logical))
+            x, NamedSharding(_auto_axes(self.mesh), self.spec(*logical))
         )
 
     def param_sharding(self, specs_tree):
@@ -97,6 +97,19 @@ class ShardingCtx:
         return jax.tree_util.tree_map(
             one, specs_tree, is_leaf=lambda x: isinstance(x, tuple)
         )
+
+
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis Auto.
+
+    ``jax.make_mesh`` builds Explicit axes by default, and
+    ``with_sharding_constraint`` only accepts specs over Auto axes."""
+    from jax.sharding import AxisType
+
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 LOCAL_CTX = ShardingCtx()  # unsharded (smoke tests, single CPU)

@@ -1,3 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the graph hot loop.
+
+:func:`pallas_compiled` is the one place that decides how a Pallas kernel
+runs: compiled by Mosaic on a TPU, in the Pallas interpreter on any other
+platform (the CPU test tier).  Kernel wrappers ask it when they trace; no
+caller chooses.
+"""
+
+from __future__ import annotations
+
+
+def pallas_compiled() -> bool:
+    """True when Pallas kernels compile for the device (a TPU backend)."""
+    import jax
+
+    return jax.default_backend() == "tpu"

@@ -4,37 +4,100 @@ Schedule (the kernel-level vertex-centric sliding window, DESIGN.md §2):
 
 - grid = (n_tiles,): one step per (TR, K) tile of ELL rows.
 - scalar prefetch carries ``tile_window[n_tiles]``; the BlockSpec index map
-  of the message table reads it, so each grid step DMAs exactly ONE
-  ``(window,)``-sized slice of the source-message array HBM->VMEM — the
-  sliding window over source vertices.  Pallas double-buffers consecutive
-  grid steps, so tiles sharing a window reuse the resident slice and the
-  DMA of the next window overlaps the current tile's compute.
-- in-VMEM gather ``table[idx]`` (TR x K lookups into a W-entry table) +
-  masked lane reduction -> per-ELL-row partials.
+  of the message table reads it, so each grid step DMAs exactly ONE window
+  of the source-message array HBM->VMEM — the sliding window over source
+  vertices.  Pallas skips the copy when consecutive steps map to the same
+  block, so tiles sharing a window reuse the resident slice and the DMA of
+  the next window overlaps the current tile's compute.
+- the window is laid out as a 2-D ``(W / L, L)`` table of ``L``-lane rows
+  (``L = 128`` at the default widths).  The in-VMEM gather ``table[idx]``
+  is written in a form Mosaic lowers: a lane-wise ``take_along_axis`` on
+  ``idx & (L - 1)`` over each table row, kept where ``idx >> log2(L)``
+  selects that row — ``W / L`` compare/select rounds per tile.
+- masked lane reduction -> one partial per ELL row, written as a ``(1, TR)``
+  block of an ``(n_tiles, 1, TR)`` output (the block's last two dims equal
+  the array's, which Mosaic accepts for any TR).
 - the tiny ``seg`` combine (partials -> rows) stays in XLA (ops.py): it is
-  O(|E|/K) work on data already in registers/VMEM scale, not worth a
-  hand-written scatter.
+  O(|E|/K) work, not worth a hand-written scatter.
 
-Tile shapes are hardware-aligned: TR=8 sublanes, K=128 lanes, W*4B = 64KB
-VMEM for the fp32 table at the default window of 16384.
+These kernels compile for a v5e at TR=8, K=128, W=16384
+(``tests/test_chip_compile.py``); the gather's ``take_along_axis`` needs
+``K == L`` there.  Off the TPU they run in the Pallas interpreter
+(:func:`repro.kernels.pallas_compiled`), where any K and W work.
 
 Two variants:
 - ``masked``  (paper-faithful layout): validity carried as a bool tile.
-- ``sentinel`` (optimized, §Perf iteration 2): invalid slots point at a
-  dedicated identity slot appended to the table — no mask tile at all,
-  cutting streamed edge bytes by the full mask plane.
+- ``sentinel``: invalid slots point at an identity-filled pad appended to
+  each window of the table — no mask tile at all, cutting streamed edge
+  bytes by the full mask plane.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 IDENTITY = {"sum": 0.0, "min": float("inf"), "max": float("-inf")}
+
+#: Tiles per ``pallas_call``.  Scalar prefetch keeps ``tile_window`` in
+#: SMEM (1 MiB on a v5e, which a 256K-tile map overflows), so longer grids
+#: are launched in chunks of this many tiles; tiles are independent, so the
+#: concatenated partials are exactly those of one launch.
+MAX_TILES_PER_CALL = 1 << 17
+
+
+def table_lanes(window: int) -> int:
+    """Lanes per table row: 128 for any window that is a multiple of 128."""
+    return math.gcd(window, 128)
+
+
+def sentinel_pad(window: int) -> int:
+    """Identity slots appended per window by the sentinel layout: one
+    ``(8, L)`` tile, so the extended table keeps 8-row alignment."""
+    return 8 * table_lanes(window)
+
+
+def _table(msgs: jax.Array, window: int) -> jax.Array:
+    """``[..., nw * window]`` messages -> ``[..., nw * window / L, L]``."""
+    lanes = table_lanes(window)
+    return msgs.reshape(*msgs.shape[:-1], -1, lanes)
+
+
+def _gather(tab_ref, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` for a ``(TR, K)`` tile of window-local indices, from
+    the resident ``(rows, L)`` window table."""
+    rows, lanes = tab_ref.shape
+    hi = idx >> (lanes.bit_length() - 1)
+    lo = idx & (lanes - 1)
+    g = jnp.zeros(idx.shape, tab_ref.dtype)
+    # Unrolled: the rows' gathers are independent, so the compiler can
+    # overlap them; as a loop each round waits out the gather's latency.
+    for j in range(rows):
+        row = jnp.broadcast_to(tab_ref[j:j + 1, :], (idx.shape[0], lanes))
+        gj = jnp.take_along_axis(row, lo, axis=1, mode="promise_in_bounds")
+        g = jnp.where(hi == j, gj, g)
+    return g
+
+
+def _over_tile_chunks(call, tile_window, tr, *row_arrays):
+    """``call(tile_window, *row_arrays)`` over chunks of at most
+    :data:`MAX_TILES_PER_CALL` tiles, partials concatenated on the last
+    axis.  ``row_arrays`` are indexed by ELL row (``tr`` rows per tile)."""
+    n_tiles = tile_window.shape[0]
+    if n_tiles <= MAX_TILES_PER_CALL:
+        return call(tile_window, *row_arrays)
+    return jnp.concatenate([
+        call(tile_window[a:a + MAX_TILES_PER_CALL],
+             *(x[a * tr:(a + MAX_TILES_PER_CALL) * tr] for x in row_arrays))
+        for a in range(0, n_tiles, MAX_TILES_PER_CALL)
+    ], axis=-1)
 
 
 def _reduce(g: jax.Array, combine: str) -> jax.Array:
@@ -46,20 +109,16 @@ def _reduce(g: jax.Array, combine: str) -> jax.Array:
 
 
 # ---------------------------------------------------------------- masked
-def _masked_kernel(combine: str, tile_window_ref, idx_ref, valid_ref, msgs_ref,
+def _masked_kernel(combine: str, tile_window_ref, idx_ref, valid_ref, tab_ref,
                    out_ref):
     """One (TR, K) tile: gather from the resident window table, mask, reduce."""
-    table = msgs_ref[...]  # [window] VMEM-resident source messages
-    idx = idx_ref[...].astype(jnp.int32)  # [TR, K] window-local indices
-    g = jnp.take(table, idx, axis=0, mode="clip")
+    g = _gather(tab_ref, idx_ref[...].astype(jnp.int32))
     ident = jnp.asarray(IDENTITY[combine], g.dtype)
     g = jnp.where(valid_ref[...], g, ident)
-    out_ref[...] = _reduce(g, combine)
+    out_ref[...] = _reduce(g, combine)[None]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("window", "tr", "combine", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("window", "tr", "combine"))
 def ell_partials_masked(
     ell_idx: jax.Array,  # [n_ell, K] int16/int32 window-local
     ell_valid: jax.Array,  # [n_ell, K] bool
@@ -69,34 +128,40 @@ def ell_partials_masked(
     window: int,
     tr: int,
     combine: str,
-    interpret: bool = True,
 ) -> jax.Array:
     """Per-ELL-row partial reductions, [n_ell]."""
-    n_ell, k = ell_idx.shape
-    n_tiles = n_ell // tr
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tr, k), lambda i, tw: (i, 0)),
-            pl.BlockSpec((tr, k), lambda i, tw: (i, 0)),
-            # THE sliding window: block index comes from the prefetched
-            # tile->window map, one W-slice of msgs resident per grid step.
-            pl.BlockSpec((window,), lambda i, tw: (tw[i],)),
-        ],
-        out_specs=pl.BlockSpec((tr,), lambda i, tw: (i,)),
-    )
-    return pl.pallas_call(
-        functools.partial(_masked_kernel, combine),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_ell,), msgs.dtype),
-        interpret=interpret,
-    )(tile_window, ell_idx, ell_valid, msgs)
+    k = ell_idx.shape[1]
+    tab = _table(msgs, window)
+    rows, lanes = window // tab.shape[-1], tab.shape[-1]
+
+    def call(tw, idx, valid):
+        n_tiles = tw.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((tr, k), lambda i, tw: (i, 0)),
+                pl.BlockSpec((tr, k), lambda i, tw: (i, 0)),
+                # THE sliding window: block index comes from the prefetched
+                # tile->window map, one window of msgs resident per step.
+                pl.BlockSpec((rows, lanes), lambda i, tw: (tw[i], 0)),
+            ],
+            out_specs=pl.BlockSpec((None, 1, tr), lambda i, tw: (i, 0, 0)),
+        )
+        out = pl.pallas_call(
+            functools.partial(_masked_kernel, combine),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tr), msgs.dtype),
+            interpret=not kernels.pallas_compiled(),
+        )(tw, idx, valid, tab)
+        return out.reshape(n_tiles * tr)
+
+    return _over_tile_chunks(call, tile_window, tr, ell_idx, ell_valid)
 
 
 # --------------------------------------------------------------- ragged
 def _ragged_kernel(combines, tile_window_ref, combine_ids_ref, idx_ref,
-                   valid_ref, msgs_ref, out_ref):
+                   valid_ref, tab_ref, out_ref):
     """One (TR, K) tile of ONE lane: gather once, reduce per combine arm,
     keep the arm this lane's ``combine_id`` selects.
 
@@ -105,11 +170,9 @@ def _ragged_kernel(combines, tile_window_ref, combine_ids_ref, idx_ref,
     combine — the bitwise contract survives the fusion.  Padding lanes carry
     an out-of-range id that matches no arm and stay at the zero init.
     """
-    table = msgs_ref[...][0]  # [window] this lane's resident source slice
-    idx = idx_ref[...].astype(jnp.int32)  # [TR, K] window-local indices
-    g = jnp.take(table, idx, axis=0, mode="clip")  # shared across arms
+    g = _gather(tab_ref, idx_ref[...].astype(jnp.int32))  # shared across arms
     cid = combine_ids_ref[pl.program_id(0)]
-    out = jnp.zeros((idx.shape[0],), g.dtype)
+    out = jnp.zeros((g.shape[0],), g.dtype)
     for ci, combine in enumerate(combines):
         ident = jnp.asarray(IDENTITY[combine], g.dtype)
         gc = jnp.where(valid_ref[...], g, ident)
@@ -117,9 +180,7 @@ def _ragged_kernel(combines, tile_window_ref, combine_ids_ref, idx_ref,
     out_ref[...] = out[None]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("window", "tr", "combines", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("window", "tr", "combines"))
 def ell_partials_ragged(
     ell_idx: jax.Array,  # [n_ell, K] int16/int32 window-local
     ell_valid: jax.Array,  # [n_ell, K] bool
@@ -130,7 +191,6 @@ def ell_partials_ragged(
     window: int,
     tr: int,
     combines: tuple,  # deduplicated combine arms, static
-    interpret: bool = True,
 ) -> jax.Array:
     """Per-ELL-row partials for ALL lanes of ALL fusion groups, [n_lanes,
     n_ell] — ONE launch where the multi path pays G (DESIGN.md §14).
@@ -139,65 +199,77 @@ def ell_partials_ragged(
     vector carries each lane's combine-arm id so the selection happens
     in-kernel instead of at launch granularity.
     """
-    n_ell, k = ell_idx.shape
-    n_tiles = n_ell // tr
+    k = ell_idx.shape[1]
     n_lanes = msgs.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_lanes, n_tiles),
-        in_specs=[
-            pl.BlockSpec((tr, k), lambda l, i, tw, cid: (i, 0)),
-            pl.BlockSpec((tr, k), lambda l, i, tw, cid: (i, 0)),
-            # Sliding window per lane: one (1, W) slice of this lane's
-            # message row resident per grid step.
-            pl.BlockSpec((1, window), lambda l, i, tw, cid: (l, tw[i])),
-        ],
-        out_specs=pl.BlockSpec((1, tr), lambda l, i, tw, cid: (l, i)),
-    )
-    return pl.pallas_call(
-        functools.partial(_ragged_kernel, combines),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_lanes, n_ell), msgs.dtype),
-        interpret=interpret,
-    )(tile_window, combine_ids, ell_idx, ell_valid, msgs)
+    tab = _table(msgs, window)
+    rows, lanes = window // tab.shape[-1], tab.shape[-1]
+
+    def call(tw, idx, valid):
+        n_tiles = tw.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_lanes, n_tiles),
+            in_specs=[
+                pl.BlockSpec((tr, k), lambda l, i, tw, cid: (i, 0)),
+                pl.BlockSpec((tr, k), lambda l, i, tw, cid: (i, 0)),
+                # Sliding window per lane: one window of this lane's
+                # message table resident per grid step.
+                pl.BlockSpec((None, rows, lanes),
+                             lambda l, i, tw, cid: (l, tw[i], 0)),
+            ],
+            out_specs=pl.BlockSpec((None, None, 1, tr),
+                                   lambda l, i, tw, cid: (l, i, 0, 0)),
+        )
+        out = pl.pallas_call(
+            functools.partial(_ragged_kernel, combines),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_lanes, n_tiles, 1, tr),
+                                           msgs.dtype),
+            interpret=not kernels.pallas_compiled(),
+        )(tw, combine_ids, idx, valid, tab)
+        return out.reshape(n_lanes, n_tiles * tr)
+
+    return _over_tile_chunks(call, tile_window, tr, ell_idx, ell_valid)
 
 
 # -------------------------------------------------------------- sentinel
-def _sentinel_kernel(combine: str, tile_window_ref, idx_ref, msgs_ref, out_ref):
-    """No mask plane: padding slots index the identity slot of the table."""
-    table = msgs_ref[...]  # [window + pad] last lane(s) hold the identity
-    idx = idx_ref[...].astype(jnp.int32)
-    g = jnp.take(table, idx, axis=0, mode="clip")
-    out_ref[...] = _reduce(g, combine)
+def _sentinel_kernel(combine: str, tile_window_ref, idx_ref, tab_ref, out_ref):
+    """No mask plane: padding slots index the identity pad of the table."""
+    g = _gather(tab_ref, idx_ref[...].astype(jnp.int32))
+    out_ref[...] = _reduce(g, combine)[None]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("window", "tr", "combine", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("window", "tr", "combine"))
 def ell_partials_sentinel(
-    ell_idx: jax.Array,  # [n_ell, K] indices into the EXTENDED window (W+pad)
+    ell_idx: jax.Array,  # [n_ell, K] indices into the EXTENDED window
     tile_window: jax.Array,
-    msgs_ext: jax.Array,  # [num_windows * (window + pad)] identity-padded
+    msgs_ext: jax.Array,  # [num_windows * window] identity-padded windows
     *,
-    window: int,  # EXTENDED window size (W + pad)
+    window: int,  # EXTENDED window size (W + sentinel_pad(W))
     tr: int,
     combine: str,
-    interpret: bool = True,
 ) -> jax.Array:
-    n_ell, k = ell_idx.shape
-    n_tiles = n_ell // tr
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tr, k), lambda i, tw: (i, 0)),
-            pl.BlockSpec((window,), lambda i, tw: (tw[i],)),
-        ],
-        out_specs=pl.BlockSpec((tr,), lambda i, tw: (i,)),
-    )
-    return pl.pallas_call(
-        functools.partial(_sentinel_kernel, combine),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_ell,), msgs_ext.dtype),
-        interpret=interpret,
-    )(tile_window, ell_idx, msgs_ext)
+    k = ell_idx.shape[1]
+    tab = _table(msgs_ext, window)
+    rows, lanes = window // tab.shape[-1], tab.shape[-1]
+
+    def call(tw, idx):
+        n_tiles = tw.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((tr, k), lambda i, tw: (i, 0)),
+                pl.BlockSpec((rows, lanes), lambda i, tw: (tw[i], 0)),
+            ],
+            out_specs=pl.BlockSpec((None, 1, tr), lambda i, tw: (i, 0, 0)),
+        )
+        out = pl.pallas_call(
+            functools.partial(_sentinel_kernel, combine),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tr), msgs_ext.dtype),
+            interpret=not kernels.pallas_compiled(),
+        )(tw, idx, tab)
+        return out.reshape(n_tiles * tr)
+
+    return _over_tile_chunks(call, tile_window, tr, ell_idx)

@@ -47,31 +47,31 @@ def _segment_combine(part, seg, rows, combine):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("window", "tr", "rows", "combine", "variant", "interpret"),
+    static_argnames=("window", "tr", "rows", "combine", "variant"),
 )
 def _update_jit(
     ell_idx, ell_valid, seg, tile_window, msgs,
-    *, window, tr, rows, combine, variant, interpret,
+    *, window, tr, rows, combine, variant,
 ):
     if variant == "masked":
         part = K.ell_partials_masked(
             ell_idx, ell_valid, tile_window, msgs,
-            window=window, tr=tr, combine=combine, interpret=interpret,
+            window=window, tr=tr, combine=combine,
         )
     else:
         part = K.ell_partials_sentinel(
             ell_idx, tile_window, msgs,
-            window=window, tr=tr, combine=combine, interpret=interpret,
+            window=window, tr=tr, combine=combine,
         )
     return _segment_combine(part, seg, rows, combine)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "tr", "rows", "combine", "interpret")
+    jax.jit, static_argnames=("window", "tr", "rows", "combine")
 )
 def _update_lanes_jit(
     ell_idx, ell_valid, seg, tile_window, msgs2d,
-    *, window, tr, rows, combine, interpret,
+    *, window, tr, rows, combine,
 ):
     """Lane-batched update: ONE traced computation covering every lane.
 
@@ -88,7 +88,7 @@ def _update_lanes_jit(
     def one_lane(msgs):
         part = K.ell_partials_masked(
             ell_idx, ell_valid, tile_window, msgs,
-            window=window, tr=tr, combine=combine, interpret=interpret,
+            window=window, tr=tr, combine=combine,
         )
         return _segment_combine(part, seg, rows, combine)
 
@@ -101,7 +101,6 @@ def ell_update(
     combine: str,
     *,
     variant: str = "masked",
-    interpret: bool = True,
 ) -> jax.Array:
     """acc[rows] for one shard.  msgs is the full |V| message array."""
     nw = ell.num_windows
@@ -113,11 +112,11 @@ def ell_update(
             jnp.asarray(ell.seg), jnp.asarray(ell.tile_window),
             jnp.asarray(msgs_p),
             window=ell.window, tr=ell.tr, rows=ell.rows, combine=combine,
-            variant=variant, interpret=interpret,
+            variant=variant,
         )
-    # Sentinel layout: extend each window by one aligned slot-group holding
-    # the combine identity; remap invalid slots to the sentinel position.
-    ext = ell.window + 128  # keep lane alignment
+    # Sentinel layout: extend each window by one aligned tile holding the
+    # combine identity; remap invalid slots to the sentinel position.
+    ext = ell.window + K.sentinel_pad(ell.window)
     msgs_e = np.full(nw * ext, IDENTITY[combine], msgs.dtype)
     for w in range(nw):
         lo, hi = w * ell.window, min((w + 1) * ell.window, msgs.shape[0])
@@ -127,7 +126,7 @@ def ell_update(
         jnp.asarray(idx), None, jnp.asarray(ell.seg),
         jnp.asarray(ell.tile_window), jnp.asarray(msgs_e),
         window=ext, tr=ell.tr, rows=ell.rows, combine=combine,
-        variant=variant, interpret=interpret,
+        variant=variant,
     )
 
 
@@ -147,8 +146,6 @@ def ell_update_batched(
     ells: Sequence[EllShard],
     msgs: np.ndarray,
     combine: str,
-    *,
-    interpret: bool = True,
 ) -> List[np.ndarray]:
     """Per-shard accumulators for N shards from ONE kernel dispatch.
 
@@ -172,7 +169,7 @@ def ell_update_batched(
         jnp.asarray(seg), jnp.asarray(tw),
         jnp.asarray(msgs_p),
         window=batch.window, tr=batch.tr, rows=next_pow2(batch.rows_total),
-        combine=combine, variant="masked", interpret=interpret,
+        combine=combine, variant="masked",
     )
     return batch.split(np.asarray(acc))
 
@@ -181,8 +178,6 @@ def ell_update_lanes(
     ell: EllShard,
     msgs: np.ndarray,  # [lanes, |V|]
     combine: str,
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     """acc[lanes, rows] for one shard against ``lanes`` message rows.
 
@@ -200,7 +195,6 @@ def ell_update_lanes(
         jnp.asarray(ell.seg), jnp.asarray(ell.tile_window),
         jnp.asarray(msgs_p),
         window=ell.window, tr=ell.tr, rows=ell.rows, combine=combine,
-        interpret=interpret,
     )
 
 
@@ -208,8 +202,6 @@ def ell_update_lanes_batched(
     ells: Sequence[EllShard],
     msgs: np.ndarray,  # [lanes, |V|]
     combine: str,
-    *,
-    interpret: bool = True,
 ) -> List[np.ndarray]:
     """Per-shard ``[lanes, rows]`` accumulators for N shards x K lanes from
     ONE dispatch — the serving hot loop's maximal amortization point: the
@@ -226,7 +218,7 @@ def ell_update_lanes_batched(
         jnp.asarray(seg), jnp.asarray(tw),
         jnp.asarray(msgs_p),
         window=batch.window, tr=batch.tr, rows=next_pow2(batch.rows_total),
-        combine=combine, interpret=interpret,
+        combine=combine,
     )
     return batch.split(np.asarray(acc))
 
@@ -235,8 +227,6 @@ def ell_update_lanes_multi(
     ells: Sequence[EllShard],
     msgs_by_group: Sequence[np.ndarray],  # each [K_g, |V|]
     combines: Sequence[str],
-    *,
-    interpret: bool = True,
 ) -> List[List[np.ndarray]]:
     """Per-shard ``[K_g, rows]`` accumulators for N shards x G program
     groups: the batch is concatenated, shape-bucketed and staged to device
@@ -271,18 +261,18 @@ def ell_update_lanes_multi(
         acc = _update_lanes_jit(
             idx_j, mask_j, seg_j, tw_j, jnp.asarray(msgs_p),
             window=batch.window, tr=batch.tr, rows=rows_pad,
-            combine=combine, interpret=interpret,
+            combine=combine,
         )
         out.append(batch.split(np.asarray(acc)))
     return out
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "tr", "rows", "combines", "interpret")
+    jax.jit, static_argnames=("window", "tr", "rows", "combines")
 )
 def _update_lanes_ragged_jit(
     ell_idx, ell_valid, seg, tile_window, combine_ids, msgs2d,
-    *, window, tr, rows, combines, interpret,
+    *, window, tr, rows, combines,
 ):
     """RaggedFuse update: ONE pallas launch covers every fusion group.
 
@@ -296,7 +286,7 @@ def _update_lanes_ragged_jit(
     """
     part = K.ell_partials_ragged(
         ell_idx, ell_valid, tile_window, combine_ids, msgs2d,
-        window=window, tr=tr, combines=combines, interpret=interpret,
+        window=window, tr=tr, combines=combines,
     )
     acc = jnp.zeros((msgs2d.shape[0], rows), msgs2d.dtype)
     for ci, combine in enumerate(combines):
@@ -307,19 +297,37 @@ def _update_lanes_ragged_jit(
     return acc
 
 
-def ragged_stage_lanes(msgs_by_group, combines: Sequence[str], n_pad_v: int):
+def _mesh_put(mesh, x, *logical):
+    """Stage a host array straight onto its shards of ``mesh`` (each device
+    receives only its slice; nothing lands on one chip first)."""
+    from jax.sharding import NamedSharding
+
+    from repro.distributed.sharding import graph_ctx
+
+    return jax.device_put(x, NamedSharding(mesh, graph_ctx(mesh).spec(*logical)))
+
+
+def ragged_stage_lanes(msgs_by_group, combines: Sequence[str], n_pad_v: int,
+                       *, mesh=None):
     """Stage the lane side of a ragged launch to device ONCE.
 
     Lane values are fixed within a sweep iteration, so the executor caches
     this across shard batches — the per-group pad+copy the multi path pays
-    on every flush is paid once per iteration instead (ISSUE 10 satellite).
+    on every flush is paid once per iteration instead.  With ``mesh`` the
+    lane matrix goes straight to its vertex shards and the combine ids are
+    replicated.
     """
     msgs_all, cids, combines_set, slices = ragged_lane_concat(
         msgs_by_group, combines, n_cols=n_pad_v
     )
+    if mesh is None:
+        msgs_d, cids_d = jnp.asarray(msgs_all), jnp.asarray(cids)
+    else:
+        msgs_d = _mesh_put(mesh, msgs_all, "lane", "vertex")
+        cids_d = _mesh_put(mesh, cids, "lane")
     return {
-        "msgs": jnp.asarray(msgs_all),
-        "cids": jnp.asarray(cids),
+        "msgs": msgs_d,
+        "cids": cids_d,
         "combines": combines_set,
         "slices": slices,
         "k_total": int(sum(int(m.shape[0]) for m in msgs_by_group)),
@@ -327,8 +335,7 @@ def ragged_stage_lanes(msgs_by_group, combines: Sequence[str], n_pad_v: int):
     }
 
 
-def ragged_dispatch(ells: Sequence[EllShard], lane_ctx, *,
-                    interpret: bool = True):
+def ragged_dispatch(ells: Sequence[EllShard], lane_ctx):
     """Launch ONE ragged update for a shard batch.
 
     Returns ``(batch, acc)`` with ``acc`` an *unforced* device array, so
@@ -339,7 +346,7 @@ def ragged_dispatch(ells: Sequence[EllShard], lane_ctx, *,
         jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(seg),
         jnp.asarray(tw), lane_ctx["cids"], lane_ctx["msgs"],
         window=batch.window, tr=batch.tr, rows=next_pow2(batch.rows_total),
-        combines=lane_ctx["combines"], interpret=interpret,
+        combines=lane_ctx["combines"],
     )
     return batch, acc
 
@@ -355,8 +362,6 @@ def ell_update_lanes_ragged(
     ells: Sequence[EllShard],
     msgs_by_group: Sequence[np.ndarray],  # each [K_g, |V|]
     combines: Sequence[str],
-    *,
-    interpret: bool = True,
 ) -> List[List[np.ndarray]]:
     """Per-shard ``[K_g, rows]`` accumulators for N shards x G groups from
     ONE ragged launch — the one-launch replacement for
@@ -379,23 +384,73 @@ def ell_update_lanes_ragged(
         return [[] for _ in msgs_by_group]
     n_pad_v = ells[0].num_windows * ells[0].window
     lane_ctx = ragged_stage_lanes(msgs_by_group, combines, n_pad_v)
-    batch, acc = ragged_dispatch(ells, lane_ctx, interpret=interpret)
+    batch, acc = ragged_dispatch(ells, lane_ctx)
     return ragged_collect(batch, acc, lane_ctx["slices"])
 
 
+#: logical axes of the stacked per-device ELL arrays a mesh step consumes:
+#: ell_idx / ell_mask ``[D, n_ell, K]``, seg ``[D, n_ell]``, tile_window
+#: ``[D, n_tiles]`` — device ``d``'s block lands on device ``d``.
+_DEVICE_AXES = (
+    ("device", None, None), ("device", None, None),
+    ("device", None), ("device", None),
+)
+
+
+def _mesh_body(backend, window, tr, rows, combine):
+    """The single-device lane body a mesh step vmaps:
+
+    - ``backend="jnp"``: :func:`repro.core.executor._ell_fn_impl` — the
+      exact function the single-device jnp lane path vmaps,
+    - ``backend="pallas"``: ``K.ell_partials_masked`` + the segment combine
+      — the exact body of :func:`_update_lanes_jit`'s ``one_lane``.
+    """
+    if backend == "jnp":
+        from repro.core.executor import _ell_fn_impl
+
+        return _ell_fn_impl(tr, rows, window, combine)
+
+    def body(ell_idx, ell_mask, seg, tile_window, msgs):
+        part = K.ell_partials_masked(
+            ell_idx, ell_mask, tile_window, msgs,
+            window=window, tr=tr, combine=combine,
+        )
+        return _segment_combine(part, seg, rows, combine)
+
+    return body
+
+
+def _mesh_jit(mesh, step, in_axes):
+    """``jax.shard_map`` + ``jax.jit`` of a mesh step whose inputs carry the
+    logical ``in_axes`` and whose outputs are the ``[D, lanes, rows]``
+    accumulator and one replicated scalar."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.distributed.sharding import graph_ctx
+
+    ctx = graph_ctx(mesh)
+    in_specs = tuple(ctx.spec(*ax) for ax in in_axes)
+    out_specs = (ctx.spec("device", "lane", None), P())
+    fn = jax.shard_map(
+        step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
+    return jax.jit(
+        fn,
+        in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
+        out_shardings=tuple(NamedSharding(mesh, s) for s in out_specs),
+    )
+
+
 @functools.lru_cache(maxsize=32)
-def _mesh_lanes_jit(mesh, backend, window, tr, rows, combine, interpret):
+def _mesh_lanes_jit(mesh, backend, window, tr, rows, combine):
     """One mesh sweep dispatch: shard_map'd lane update over a device axis.
 
     Device ``d`` receives its own stacked ELL block (leading axis sharded
     over every mesh axis) plus its slice of the lane-message matrix,
     all-gathers the full message array (the SEM working set, DESIGN.md §10)
-    and runs THE single-device lane computation on its block:
-
-    - ``backend="jnp"``: the body is :func:`repro.core.executor._ell_fn_impl`
-      — the exact function the single-device jnp lane path vmaps,
-    - ``backend="pallas"``: ``K.ell_partials_masked`` + the segment combine
-      — the exact body of :func:`_update_lanes_jit`'s ``one_lane``.
+    and runs THE single-device lane computation (:func:`_mesh_body`) on its
+    block.
 
     Each destination row still belongs to exactly one device (the paper's
     lock-free property lifted to SPMD), so per-shard accumulators are
@@ -403,27 +458,9 @@ def _mesh_lanes_jit(mesh, backend, window, tr, rows, combine, interpret):
     ``psum``'d count of non-identity accumulator slots — the SPMD activity
     proxy the iteration stats record without a host round-trip per device.
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.distributed.sharding import graph_ctx
-
-    ctx = graph_ctx(mesh)
     axes = tuple(mesh.axis_names)
     ident = IDENTITY[combine]
-
-    if backend == "jnp":
-        from repro.core.executor import _ell_fn_impl
-
-        body = _ell_fn_impl(tr, rows, window, combine)
-    else:
-
-        def body(ell_idx, ell_mask, seg, tile_window, msgs):
-            part = K.ell_partials_masked(
-                ell_idx, ell_mask, tile_window, msgs,
-                window=window, tr=tr, combine=combine, interpret=interpret,
-            )
-            return _segment_combine(part, seg, rows, combine)
+    body = _mesh_body(backend, window, tr, rows, combine)
 
     def step(idx, mask, seg, tw, msgs_local):
         # Leading axis is this device's single ELL block.
@@ -438,53 +475,21 @@ def _mesh_lanes_jit(mesh, backend, window, tr, rows, combine, interpret):
         )
         return acc[None], touched
 
-    in_specs = (
-        ctx.spec("device", None, None),  # ell_idx   [D, n_ell, K]
-        ctx.spec("device", None, None),  # ell_mask  [D, n_ell, K]
-        ctx.spec("device", None),        # seg       [D, n_ell]
-        ctx.spec("device", None),        # tile_window [D, n_tiles]
-        ctx.spec("lane", "vertex"),      # msgs      [K_g, n_pad_dev]
-    )
-    out_specs = (ctx.spec("device", "lane", None), P())
-    fn = shard_map(
-        step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-    return jax.jit(
-        fn,
-        in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
-        out_shardings=tuple(NamedSharding(mesh, s) for s in out_specs),
-    )
+    return _mesh_jit(mesh, step, _DEVICE_AXES + (("lane", "vertex"),))
 
 
-def ell_update_lanes_mesh_multi(
-    device_ells: Sequence[Sequence[EllShard]],  # [D] lists, device order
-    msgs_by_group: Sequence[np.ndarray],  # each [K_g, |V|]
-    combines: Sequence[str],
-    *,
-    mesh,
-    backend: str = "pallas",
-    interpret: bool = True,
-):
-    """Mesh sweeps' dispatch point: 1 host read, G x D device slices.
+def _stage_device_batches(device_ells, mesh):
+    """Concatenate every device's shard batch with the single-device
+    :func:`_prep_batch` discipline, pad them to COMMON (pow2-bucketed)
+    shapes so the round is one SPMD program, and stage the stacked
+    ``[D, ...]`` arrays straight onto their devices.  The common padding is
+    the usual identity padding, so each shard's accumulator is bitwise what
+    :func:`ell_update_lanes_batched` computes for its device's batch alone.
 
-    ``device_ells[d]`` holds the shards device ``d`` owns this round (the
-    host read each of them ONCE; empty lists idle their device through the
-    SPMD program).  Every device's batch is concatenated with the same
-    :func:`_prep_batch` discipline as the single-device path, then padded
-    to COMMON (pow2-bucketed) shapes so the whole round is one SPMD
-    program; the common padding is the usual identity padding, so each
-    shard's accumulator is bitwise what :func:`ell_update_lanes_batched`
-    computes for its device's batch alone.
-
-    Returns ``(accs_by_group, touched_by_group)`` where
-    ``accs_by_group[g][d]`` lists per-shard ``[K_g, rows]`` accumulators
-    for device ``d`` (empty for idle devices) and ``touched_by_group[g]``
-    is the psum'd non-identity slot count (SPMD activity proxy).
+    Returns ``(batches, staged, first, rows_pad)``, or None when every
+    device's list is empty.
     """
-    if len(msgs_by_group) != len(combines):
-        raise ValueError("one combine per message group")
-    n_dev = int(np.prod(mesh.devices.shape))
+    n_dev = int(mesh.devices.size)
     if len(device_ells) != n_dev:
         raise ValueError(
             f"device_ells has {len(device_ells)} slots for a {n_dev}-device mesh"
@@ -495,11 +500,9 @@ def ell_update_lanes_mesh_multi(
         if len(ells)
     }
     if not batches:
-        return [[[] for _ in device_ells] for _ in msgs_by_group], [0] * len(
-            msgs_by_group
-        )
+        return None
     first = next(iter(batches.values()))[0]
-    window, tr, k = first.window, first.tr, first.k
+    tr, k = first.tr, first.k
     n_ell_pad = bucket_rows(max(t[1].shape[0] for t in batches.values()), tr)
     rows_pad = next_pow2(max(t[0].rows_total for t in batches.values()))
 
@@ -512,6 +515,41 @@ def ell_update_lanes_mesh_multi(
             idx, mask, seg, tw, idx.shape[0], tr, n_ell_pad
         )
         idx_all[d], mask_all[d], seg_all[d], tw_all[d] = idx, mask, seg, tw
+    staged = tuple(
+        _mesh_put(mesh, x, *ax)
+        for x, ax in zip((idx_all, mask_all, seg_all, tw_all), _DEVICE_AXES)
+    )
+    return batches, staged, first, rows_pad
+
+
+def ell_update_lanes_mesh_multi(
+    device_ells: Sequence[Sequence[EllShard]],  # [D] lists, device order
+    msgs_by_group: Sequence[np.ndarray],  # each [K_g, |V|]
+    combines: Sequence[str],
+    *,
+    mesh,
+    backend: str = "pallas",
+):
+    """Mesh sweeps' dispatch point: 1 host read, G x D device slices.
+
+    ``device_ells[d]`` holds the shards device ``d`` owns this round (the
+    host read each of them ONCE; empty lists idle their device through the
+    SPMD program); see :func:`_stage_device_batches` for the padding.
+
+    Returns ``(accs_by_group, touched_by_group)`` where
+    ``accs_by_group[g][d]`` lists per-shard ``[K_g, rows]`` accumulators
+    for device ``d`` (empty for idle devices) and ``touched_by_group[g]``
+    is the psum'd non-identity slot count (SPMD activity proxy).
+    """
+    if len(msgs_by_group) != len(combines):
+        raise ValueError("one combine per message group")
+    staged_round = _stage_device_batches(device_ells, mesh)
+    if staged_round is None:
+        return [[[] for _ in device_ells] for _ in msgs_by_group], [0] * len(
+            msgs_by_group
+        )
+    batches, staged, first, rows_pad = staged_round
+    n_dev = len(device_ells)
 
     # Messages: pad to full windows (gathers never pass n_pad_v), then to a
     # multiple of n_dev so the vertex axis shards evenly; the tail past
@@ -519,13 +557,8 @@ def ell_update_lanes_mesh_multi(
     n_pad_v = first.num_windows * first.window
     n_pad_dev = -(-n_pad_v // n_dev) * n_dev
 
-    fn_cache = {}
     accs_by_group = []
     touched_by_group = []
-    idx_j, mask_j, seg_j, tw_j = (
-        jnp.asarray(idx_all), jnp.asarray(mask_all),
-        jnp.asarray(seg_all), jnp.asarray(tw_all),
-    )
     for msgs, combine in zip(msgs_by_group, combines):
         if msgs.ndim != 2:
             raise ValueError(
@@ -533,13 +566,10 @@ def ell_update_lanes_mesh_multi(
             )
         msgs_p = np.zeros((msgs.shape[0], n_pad_dev), msgs.dtype)
         msgs_p[:, : msgs.shape[1]] = msgs
-        if combine not in fn_cache:
-            fn_cache[combine] = _mesh_lanes_jit(
-                mesh, backend, window, tr, rows_pad, combine, interpret
-            )
-        acc_all, touched = fn_cache[combine](
-            idx_j, mask_j, seg_j, tw_j, jnp.asarray(msgs_p)
+        fn = _mesh_lanes_jit(
+            mesh, backend, first.window, first.tr, rows_pad, combine
         )
+        acc_all, touched = fn(*staged, _mesh_put(mesh, msgs_p, "lane", "vertex"))
         acc_all = np.asarray(acc_all)
         accs_by_group.append(
             [
@@ -552,8 +582,7 @@ def ell_update_lanes_mesh_multi(
 
 
 @functools.lru_cache(maxsize=32)
-def _mesh_lanes_ragged_jit(mesh, backend, window, tr, rows, combines,
-                           interpret):
+def _mesh_lanes_ragged_jit(mesh, backend, window, tr, rows, combines):
     """RaggedFuse under the mesh: ONE shard_map step for ALL groups.
 
     Same SPMD schedule as :func:`_mesh_lanes_jit` — per-device ELL block,
@@ -566,32 +595,8 @@ def _mesh_lanes_ragged_jit(mesh, backend, window, tr, rows, combines,
     identity entries both stay zero, so the psum'd touched count (the SPMD
     activity proxy) is unpolluted.
     """
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.distributed.sharding import graph_ctx
-
-    ctx = graph_ctx(mesh)
     axes = tuple(mesh.axis_names)
-
-    if backend == "jnp":
-        from repro.core.executor import _ell_fn_impl
-
-        bodies = [_ell_fn_impl(tr, rows, window, c) for c in combines]
-    else:
-
-        def _mk(combine):
-            def body(ell_idx, ell_mask, seg, tile_window, msgs):
-                part = K.ell_partials_masked(
-                    ell_idx, ell_mask, tile_window, msgs,
-                    window=window, tr=tr, combine=combine,
-                    interpret=interpret,
-                )
-                return _segment_combine(part, seg, rows, combine)
-
-            return body
-
-        bodies = [_mk(c) for c in combines]
+    bodies = [_mesh_body(backend, window, tr, rows, c) for c in combines]
 
     def step(idx, mask, seg, tw, cids, msgs_local):
         idx, mask, seg, tw = idx[0], mask[0], seg[0], tw[0]
@@ -610,33 +615,20 @@ def _mesh_lanes_ragged_jit(mesh, backend, window, tr, rows, combines,
         touched = jax.lax.psum((acc != ident_vec[:, None]).sum(), axes)
         return acc[None], touched
 
-    in_specs = (
-        ctx.spec("device", None, None),  # ell_idx   [D, n_ell, K]
-        ctx.spec("device", None, None),  # ell_mask  [D, n_ell, K]
-        ctx.spec("device", None),        # seg       [D, n_ell]
-        ctx.spec("device", None),        # tile_window [D, n_tiles]
-        ctx.spec("lane"),                # combine_ids [k_pad] replicated
-        ctx.spec("lane", "vertex"),      # msgs      [k_pad, n_pad_dev]
-    )
-    out_specs = (ctx.spec("device", "lane", None), P())
-    fn = shard_map(
-        step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-    return jax.jit(
-        fn,
-        in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
-        out_shardings=tuple(NamedSharding(mesh, s) for s in out_specs),
+    return _mesh_jit(
+        mesh, step, _DEVICE_AXES + (("lane",), ("lane", "vertex"))
     )
 
 
 def mesh_ragged_stage_lanes(msgs_by_group, combines: Sequence[str],
-                            n_pad_v: int, n_dev: int):
+                            n_pad_v: int, mesh):
     """Mesh variant of :func:`ragged_stage_lanes`: the vertex axis is
-    additionally padded to a multiple of ``n_dev`` so it shards evenly (the
-    tail past ``n_pad_v`` is never addressed by a valid slot)."""
+    additionally padded to a multiple of the device count so it shards
+    evenly (the tail past ``n_pad_v`` is never addressed by a valid slot),
+    and the lane matrix is staged straight onto its vertex shards."""
+    n_dev = int(mesh.devices.size)
     n_pad_dev = -(-n_pad_v // n_dev) * n_dev
-    return ragged_stage_lanes(msgs_by_group, combines, n_pad_dev)
+    return ragged_stage_lanes(msgs_by_group, combines, n_pad_dev, mesh=mesh)
 
 
 def mesh_ragged_dispatch(
@@ -645,7 +637,6 @@ def mesh_ragged_dispatch(
     *,
     mesh,
     backend: str = "pallas",
-    interpret: bool = True,
 ):
     """Launch ONE SPMD step covering every group for this device round.
 
@@ -654,44 +645,17 @@ def mesh_ragged_dispatch(
     host decode while the step is in flight.  ``None`` when every device's
     shard list is empty.
     """
-    n_dev = int(np.prod(mesh.devices.shape))
-    if len(device_ells) != n_dev:
-        raise ValueError(
-            f"device_ells has {len(device_ells)} slots for a {n_dev}-device mesh"
-        )
-    batches = {
-        d: _prep_batch(ells)
-        for d, ells in enumerate(device_ells)
-        if len(ells)
-    }
-    if not batches:
+    staged_round = _stage_device_batches(device_ells, mesh)
+    if staged_round is None:
         return None
-    first = next(iter(batches.values()))[0]
-    window, tr, k = first.window, first.tr, first.k
-    n_ell_pad = bucket_rows(max(t[1].shape[0] for t in batches.values()), tr)
-    rows_pad = next_pow2(max(t[0].rows_total for t in batches.values()))
-
-    idx_all = np.zeros((n_dev, n_ell_pad, k), dtype=first.ell_idx.dtype)
-    mask_all = np.zeros((n_dev, n_ell_pad, k), dtype=bool)
-    seg_all = np.zeros((n_dev, n_ell_pad), dtype=np.int32)
-    tw_all = np.zeros((n_dev, n_ell_pad // tr), dtype=np.int32)
-    for d, (batch, idx, mask, seg, tw) in batches.items():
-        idx, mask, seg, tw = pad_ell_arrays(
-            idx, mask, seg, tw, idx.shape[0], tr, n_ell_pad
-        )
-        idx_all[d], mask_all[d], seg_all[d], tw_all[d] = idx, mask, seg, tw
-
+    batches, staged, first, rows_pad = staged_round
     fn = _mesh_lanes_ragged_jit(
-        mesh, backend, window, tr, rows_pad, lane_ctx["combines"], interpret
+        mesh, backend, first.window, first.tr, rows_pad, lane_ctx["combines"]
     )
-    acc_all, touched = fn(
-        jnp.asarray(idx_all), jnp.asarray(mask_all),
-        jnp.asarray(seg_all), jnp.asarray(tw_all),
-        lane_ctx["cids"], lane_ctx["msgs"],
-    )
+    acc_all, touched = fn(*staged, lane_ctx["cids"], lane_ctx["msgs"])
     return {
         "batches": batches,
-        "n_dev": n_dev,
+        "n_dev": len(device_ells),
         "acc": acc_all,
         "touched": touched,
         "slices": lane_ctx["slices"],
@@ -721,7 +685,6 @@ def ell_update_lanes_mesh_ragged(
     *,
     mesh,
     backend: str = "pallas",
-    interpret: bool = True,
 ):
     """Mesh RaggedFuse entry point: 1 host read, ONE SPMD step, D device
     slices — where :func:`ell_update_lanes_mesh_multi` pays G steps.
@@ -737,40 +700,13 @@ def ell_update_lanes_mesh_ragged(
             raise ValueError(
                 f"lane update needs [lanes, |V|] messages, got {msgs.shape}"
             )
-    n_dev = int(np.prod(mesh.devices.shape))
     first = next((ells[0] for ells in device_ells if len(ells)), None)
     if first is None:
         return [[[] for _ in device_ells] for _ in msgs_by_group], 0
     lane_ctx = mesh_ragged_stage_lanes(
-        msgs_by_group, combines, first.num_windows * first.window, n_dev
+        msgs_by_group, combines, first.num_windows * first.window, mesh
     )
     handle = mesh_ragged_dispatch(
-        device_ells, lane_ctx, mesh=mesh, backend=backend, interpret=interpret
+        device_ells, lane_ctx, mesh=mesh, backend=backend
     )
     return mesh_ragged_collect(handle)
-
-
-def ell_update_arrays(
-    idx_global: jax.Array,  # [n_ell, K] int32 global source ids
-    valid: jax.Array,
-    seg: jax.Array,
-    msgs: jax.Array,  # [num_vertices]
-    rows: int,
-    combine: str,
-) -> jax.Array:
-    """Global-index variant (distributed path): XLA gather + segment combine.
-
-    Used inside shard_map where the full message array is the all-gathered
-    SEM working set; the windowed Pallas kernel is the single-device path.
-    """
-    ident = jnp.asarray(IDENTITY[combine], msgs.dtype)
-    g = jnp.take(msgs, idx_global, axis=0, mode="clip")
-    g = jnp.where(valid, g, ident)
-    if combine == "sum":
-        part = g.sum(axis=1)
-        return jax.ops.segment_sum(part, seg, num_segments=rows)
-    if combine == "min":
-        part = g.min(axis=1)
-        return jax.ops.segment_min(part, seg, num_segments=rows)
-    part = g.max(axis=1)
-    return jax.ops.segment_max(part, seg, num_segments=rows)

@@ -15,13 +15,13 @@ from __future__ import annotations
 import numpy as np
 
 
-def mesh_device_error(shape, have: int) -> RuntimeError:
-    """The uniform too-few-devices error: count derived from ``shape``."""
+def mesh_device_error(shape, have: int, platform: str) -> RuntimeError:
+    """The uniform too-few-devices error: count derived from ``shape``,
+    naming the platform and device count JAX found."""
     need = int(np.prod(shape))
     return RuntimeError(
-        f"mesh shape {tuple(shape)} needs {need} devices, have {have} — "
-        f"run under XLA_FLAGS=--xla_force_host_platform_device_count={need} "
-        "(set BEFORE jax initialises; dryrun.py does this automatically)"
+        f"mesh shape {tuple(shape)} needs {need} devices, have {have} "
+        f"on platform {platform!r}"
     )
 
 
@@ -37,7 +37,7 @@ def _take_devices(shape):
     need = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) < need:
-        raise mesh_device_error(shape, len(devices))
+        raise mesh_device_error(shape, len(devices), devices[0].platform)
     return devices[:need]
 
 

@@ -213,3 +213,38 @@ def test_flash_attention_block_sweep(block_q, block_k):
         np.asarray(out), np.asarray(ref.reshape(2, 256, 64)),
         rtol=2e-3, atol=2e-3,
     )
+
+
+def test_spmv_ell_tile_chunks_match_one_launch(monkeypatch):
+    """Grids longer than MAX_TILES_PER_CALL launch in chunks (the tile map
+    lives in SMEM); the chunked partials equal one launch's exactly."""
+    import jax
+
+    from repro.kernels.spmv_ell import kernel as Kn
+
+    g = rmat_graph(1500, 20000, seed=9)
+    meta, shards = preprocess(g, num_shards=1)
+    e = csr_to_ell(shards[0], g.num_vertices, window=256, k=16, tr=8)
+    msgs = np.random.default_rng(2).random(e.num_windows * 256).astype(np.float32)
+    args = (jnp.asarray(e.ell_idx), jnp.asarray(e.ell_mask),
+            jnp.asarray(e.tile_window), jnp.asarray(msgs))
+    lane_args = (jnp.asarray(e.ell_idx), jnp.asarray(e.ell_mask),
+                 jnp.asarray(e.tile_window), jnp.asarray([0, 1], jnp.int32),
+                 jnp.asarray(np.stack([msgs, msgs[::-1].copy()])))
+
+    def run():
+        jax.clear_caches()
+        return (
+            np.asarray(Kn.ell_partials_masked(*args, window=256, tr=8,
+                                              combine="sum")),
+            np.asarray(Kn.ell_partials_ragged(*lane_args, window=256, tr=8,
+                                              combines=("min", "sum"))),
+        )
+
+    one = run()
+    monkeypatch.setattr(Kn, "MAX_TILES_PER_CALL", 3)
+    assert e.n_tiles > 3
+    chunked = run()
+    jax.clear_caches()
+    for a, b in zip(one, chunked):
+        assert np.array_equal(a, b)

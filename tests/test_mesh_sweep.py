@@ -389,7 +389,8 @@ _ERR_SCRIPT = textwrap.dedent(
         except RuntimeError as e:
             msg = str(e)
             assert f"needs {needs} devices, have 8" in msg, msg
-            assert f"device_count={needs}" in msg, msg
+            assert "platform 'cpu'" in msg, msg
+            assert "xla_force_host_platform" not in msg, msg
 
     # a 4-device mesh on the 8-device host works (prefix, no truncation)
     m = make_host_mesh((4,), ("dev",))
