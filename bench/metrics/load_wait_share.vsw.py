@@ -1,0 +1,10 @@
+"""Exposed shard-load wait over iteration time, summed over the window's
+``IterStats``, in %."""
+
+
+def read(ctx):
+    its = ctx.get("iter_stats") or []
+    total = sum(i.time_s for i in its)
+    if not total:
+        return None
+    return 100.0 * sum(i.load_wait_s for i in its) / total
