@@ -361,51 +361,49 @@ def _jnp_ell_lanes_ragged_fn(
     return jax.jit(fn)
 
 
-def _ragged_stage_lanes(msgs_by_group, combines, n_pad_v: int):
-    """Stage the concatenated lane state of ALL groups to device once per
-    sweep iteration (reused across every shard batch — ISSUE 10 satellite:
-    no re-pad per flush while lane membership is unchanged)."""
-    import jax.numpy as jnp
-
-    msgs_all, cids, combines_set, slices = ragged_lane_concat(
-        msgs_by_group, combines, n_cols=n_pad_v
-    )
-    return {
-        "msgs": jnp.asarray(msgs_all),
-        "cids": jnp.asarray(cids),
-        "combines": combines_set,
-        "slices": slices,
-        "k_total": int(sum(int(m.shape[0]) for m in msgs_by_group)),
-        "k_pad": int(msgs_all.shape[0]),
-    }
-
-
-def _ragged_dispatch_jnp(ells: List[EllShard], lane_ctx):
-    """Launch ONE jnp ragged update; the accumulator is left unforced so
-    the caller can overlap the next batch's decode (double buffering)."""
-    import jax.numpy as jnp
-
-    batch, n_ell_pad, idx, mask, seg, tw = _staged_batch(ells)
-    rows_pad = next_pow2(batch.rows_total)
+def _ragged_launch_jnp(batch, n_ell_pad: int, staged, lane_ctx):
+    """Launch ONE jnp ragged update on a staged batch; the accumulator is
+    left unforced so the caller can overlap the next batch's decode
+    (double buffering)."""
     fn = _jnp_ell_lanes_ragged_fn(
-        n_ell_pad, batch.k, batch.tr, rows_pad, batch.window,
-        lane_ctx["combines"],
+        n_ell_pad, batch.k, batch.tr, next_pow2(batch.rows_total),
+        batch.window, lane_ctx["combines"],
     )
-    acc = fn(jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(seg),
-             jnp.asarray(tw), lane_ctx["cids"], lane_ctx["msgs"])
-    return batch, acc
+    return fn(*staged, lane_ctx["cids"], lane_ctx["msgs"])
 
 
-def _ragged_dispatch_pallas(ells: List[EllShard], lane_ctx):
+def _ragged_launch_pallas(batch, n_ell_pad: int, staged, lane_ctx):
     from repro.kernels.spmv_ell import ops as spmv_ops
 
-    return spmv_ops.ragged_dispatch(ells, lane_ctx)
+    return spmv_ops.ragged_launch(batch, staged, lane_ctx)
+
+
+def _ragged_put(idx, mask, seg, tw, lanes_host, lane_ctx):
+    """Copy a staged batch to the device, and the lane state when
+    ``lanes_host`` (a :func:`ragged_lane_concat` result) is given; returns
+    the device arrays and the lane context (``lane_ctx`` when unchanged)."""
+    import jax.numpy as jnp
+
+    staged = tuple(jnp.asarray(x) for x in (idx, mask, seg, tw))
+    if lanes_host is None:
+        return staged, lane_ctx
+    msgs_all, cids, combines_set, slices = lanes_host
+    return staged, {"msgs": jnp.asarray(msgs_all), "cids": jnp.asarray(cids),
+                    "combines": combines_set, "slices": slices}
 
 
 def _ragged_collect(batch, acc, group_slices) -> List[List[np.ndarray]]:
-    """Force a ragged accumulator and slice per group per shard."""
-    acc = np.asarray(acc)
-    return [batch.split(acc[sl]) for sl in group_slices]
+    """Force a ragged accumulator and slice per group per shard.
+
+    ``exec.wait`` covers only the block on the device; the rest of
+    ``exec.collect`` is the copy back and the split."""
+    import jax
+
+    with trace.span("exec.collect"):
+        with trace.span("exec.wait"):
+            jax.block_until_ready(acc)
+        acc = np.asarray(acc)
+        return [batch.split(acc[sl]) for sl in group_slices]
 
 
 def _update_shard_pallas_lanes(
@@ -467,15 +465,34 @@ _MULTI_LANE_BACKENDS: Dict[str, Callable] = {
 }
 
 _RAGGED_LANE_BACKENDS: Dict[str, Callable] = {
-    "jnp": _ragged_dispatch_jnp,
-    "pallas": _ragged_dispatch_pallas,
+    "jnp": _ragged_launch_jnp,
+    "pallas": _ragged_launch_pallas,
 }
 
 #: One program group's dispatch request for ``run_groups``: the group's
 #: ``[K_g, |V|]`` message matrix and its combine monoid, or None when the
 #: group has nothing to dispatch for these shards (every lane masked off /
 #: already retired) — the shard stream is still consumed once.
+#: ``run_groups``' keywords describe the call for tracing only: ``masked``
+#: marks a dispatch of a lane-masked flush, and ``lanes_live`` counts the
+#: rows that carry a query (default: every row of every live group).
 GroupDispatch = Optional[Tuple[np.ndarray, str]]
+
+
+def _dispatch_counters(ells, slots: int, masked: bool, lanes_live: int,
+                       lanes_pad: int, copied) -> Dict:
+    """The attributes of one ragged ``exec.dispatch`` span: the work it
+    launched (real edges from shard metadata, ELL slots after row
+    bucketing, lanes carrying a query against lanes launched) and the bytes
+    it copied to the device.  Computed only while a tracer is installed."""
+    return {
+        "masked": masked,
+        "edges": sum(int(e.nnz) for e in ells),
+        "slots": int(slots),
+        "lanes_live": int(lanes_live),
+        "lanes_pad": int(lanes_pad),
+        "h2d_bytes": sum(int(x.nbytes) for x in copied),
+    }
 
 
 # --------------------------------------------------------------------------
@@ -570,11 +587,16 @@ class PerShardExecutor:
         loaded: Iterable[LoadedShard],
         groups: Sequence[GroupDispatch],
         stats: Optional[ExecStats] = None,
+        *,
+        masked: bool = False,
+        lanes_live: Optional[int] = None,
     ) -> Iterator[Tuple[int, ExecResult]]:
         """Multi-group dispatch (fused sweeps): consume each loaded shard
         ONCE and dispatch it per live program group — one load+decode, G
         backend calls.  Yields ``(group_index, result)``; ``None`` entries
-        in ``groups`` are skipped without a dispatch.
+        in ``groups`` are skipped without a dispatch.  ``masked`` and
+        ``lanes_live`` are recorded by the ragged paths only
+        (:data:`GroupDispatch`).
         """
         for ls in loaded:
             ref = ls.ref
@@ -663,6 +685,9 @@ class BatchedEllExecutor:
         loaded: Iterable[LoadedShard],
         groups: Sequence[GroupDispatch],
         stats: Optional[ExecStats] = None,
+        *,
+        masked: bool = False,
+        lanes_live: Optional[int] = None,
     ) -> Iterator[Tuple[int, ExecResult]]:
         """Multi-group batched dispatch: up to ``batch_shards`` consecutive
         shards are concatenated ONCE (shared decode + concat + pad staging)
@@ -672,7 +697,8 @@ class BatchedEllExecutor:
         if not self.lanes:
             raise RuntimeError("run_groups needs a lane executor")
         if self.ragged:
-            yield from self._run_groups_ragged(loaded, groups, stats)
+            yield from self._run_groups_ragged(loaded, groups, stats,
+                                               masked, lanes_live)
             return
         buf: List[LoadedShard] = []
         for ls in loaded:
@@ -711,7 +737,7 @@ class BatchedEllExecutor:
                     batch_size=len(buf),
                 )
 
-    def _run_groups_ragged(self, loaded, groups, stats):
+    def _run_groups_ragged(self, loaded, groups, stats, masked, lanes_live):
         """RaggedFuse hot loop: 1 load, 1 concat, ONE kernel launch per
         batch covering every live group, with the collect of batch ``i``
         deferred until batch ``i+1`` has been dispatched — the launch stays
@@ -730,21 +756,36 @@ class BatchedEllExecutor:
         def dispatch(buf):
             nonlocal lane_ctx
             t0 = time.perf_counter()
+            ells = [ls.ell for ls in buf]
             with trace.span(
                 "exec.dispatch",
                 shards=len(buf),
                 groups=len(live),
                 backend=self.backend_name,
                 ragged=True,
-            ):
-                if lane_ctx is None:
-                    ell = buf[0].ell
-                    lane_ctx = _ragged_stage_lanes(
-                        [ga[0] for _, ga in live],
-                        [ga[1] for _, ga in live],
-                        ell.num_windows * ell.window,
-                    )
-                batch, acc = self._ragged_fn([ls.ell for ls in buf], lane_ctx)
+            ) as sp:
+                with trace.span("exec.stage"):
+                    batch, n_ell_pad, idx, mask, seg, tw = _staged_batch(ells)
+                    lanes_host = None
+                    if lane_ctx is None:
+                        lanes_host = ragged_lane_concat(
+                            [ga[0] for _, ga in live],
+                            [ga[1] for _, ga in live],
+                            n_cols=batch.num_windows * batch.window,
+                        )
+                with trace.span("exec.put"):
+                    staged, lane_ctx = _ragged_put(idx, mask, seg, tw,
+                                                   lanes_host, lane_ctx)
+                with trace.span("exec.launch"):
+                    acc = self._ragged_fn(batch, n_ell_pad, staged, lane_ctx)
+                if trace.active() is not None:
+                    sp.set(**_dispatch_counters(
+                        ells, n_ell_pad * batch.k, masked,
+                        k_total if lanes_live is None else lanes_live,
+                        int(lane_ctx["msgs"].shape[0]),
+                        (idx, mask, seg, tw) + (lanes_host[:2] if lanes_host
+                                                else ()),
+                    ))
             if stats is not None:
                 stats.dispatches += 1
                 stats.ragged_dispatches += 1
@@ -853,9 +894,13 @@ class MeshLaneExecutor:
         loaded: Iterable[LoadedShard],
         groups: Sequence[GroupDispatch],
         stats: Optional[ExecStats] = None,
+        *,
+        masked: bool = False,
+        lanes_live: Optional[int] = None,
     ) -> Iterator[Tuple[int, ExecResult]]:
         if self.ragged:
-            yield from self._run_groups_ragged(loaded, groups, stats)
+            yield from self._run_groups_ragged(loaded, groups, stats,
+                                               masked, lanes_live)
             return
         n_dev = self.partition.n_dev
         bufs: List[List[LoadedShard]] = [[] for _ in range(n_dev)]
@@ -868,7 +913,7 @@ class MeshLaneExecutor:
         if any(bufs):
             yield from self._flush(bufs, groups, stats)
 
-    def _run_groups_ragged(self, loaded, groups, stats):
+    def _run_groups_ragged(self, loaded, groups, stats, masked, lanes_live):
         """One SPMD step (or emulated round) per flush for ALL groups, with
         batch ``i``'s collect deferred until batch ``i+1``'s dispatch is in
         flight — the mesh double-buffer (DESIGN.md §14)."""
@@ -881,6 +926,8 @@ class MeshLaneExecutor:
         lane_ctx = None  # staged on first jax flush, reused across rounds
         k_total = sum(int(ga[0].shape[0]) for _, ga in live)
         if self.backend_name != "numpy":
+            import jax
+
             from repro.kernels.spmv_ell import ops as spmv_ops
 
         def dispatch(bufs):
@@ -894,7 +941,7 @@ class MeshLaneExecutor:
                 devices=sum(1 for b in bufs if b),
                 backend=self.backend_name,
                 ragged=True,
-            ):
+            ) as sp:
                 if self.backend_name == "numpy":
                     fn = LANE_BACKENDS["numpy"]
                     results = []
@@ -907,18 +954,45 @@ class MeshLaneExecutor:
                                 results.append((gi, ls, acc, len(buf)))
                     handle = ("numpy", results, None)
                 else:
-                    if lane_ctx is None:
-                        ell = next(ls.ell for b in bufs for ls in b)
-                        lane_ctx = spmv_ops.mesh_ragged_stage_lanes(
-                            [ga[0] for _, ga in live],
-                            [ga[1] for _, ga in live],
-                            ell.num_windows * ell.window, self.mesh,
+                    with trace.span("exec.stage"):
+                        batches, arrays, first, rows_pad = (
+                            spmv_ops.pack_device_batches(
+                                [[ls.ell for ls in buf] for buf in bufs],
+                                n_dev,
+                            )
                         )
-                    h = spmv_ops.mesh_ragged_dispatch(
-                        [[ls.ell for ls in buf] for buf in bufs], lane_ctx,
-                        mesh=self.mesh, backend=self.backend_name,
-                    )
+                        lanes_host = None
+                        if lane_ctx is None:
+                            lanes_host = ragged_lane_concat(
+                                [ga[0] for _, ga in live],
+                                [ga[1] for _, ga in live],
+                                n_cols=spmv_ops.mesh_lane_cols(
+                                    first.num_windows * first.window,
+                                    self.mesh,
+                                ),
+                            )
+                    with trace.span("exec.put"):
+                        staged = spmv_ops.put_device_batches(arrays,
+                                                             self.mesh)
+                        if lanes_host is not None:
+                            lane_ctx = spmv_ops.ragged_lanes_put(
+                                lanes_host, mesh=self.mesh
+                            )
+                    with trace.span("exec.launch"):
+                        h = spmv_ops.mesh_ragged_launch(
+                            batches, staged, first, rows_pad, lane_ctx,
+                            mesh=self.mesh, backend=self.backend_name,
+                        )
                     handle = ("mesh", h, list(bufs))
+                    if trace.active() is not None:
+                        sp.set(**_dispatch_counters(
+                            [ls.ell for buf in bufs for ls in buf],
+                            arrays[0].size,
+                            masked,
+                            k_total if lanes_live is None else lanes_live,
+                            int(lane_ctx["msgs"].shape[0]),
+                            arrays + (lanes_host[:2] if lanes_host else ()),
+                        ))
             if stats is not None:
                 stats.dispatches += 1
                 stats.ragged_dispatches += 1
@@ -952,7 +1026,12 @@ class MeshLaneExecutor:
             else:
                 results = []
                 if payload is not None:
-                    accs_by_group, _ = spmv_ops.mesh_ragged_collect(payload)
+                    with trace.span("exec.collect"):
+                        with trace.span("exec.wait"):
+                            jax.block_until_ready(payload["acc"])
+                        accs_by_group, _ = spmv_ops.mesh_ragged_collect(
+                            payload
+                        )
                     for (gi, _), accs_dev in zip(live, accs_by_group):
                         for buf, accs in zip(bufs, accs_dev):
                             for ls, acc in zip(buf, accs):

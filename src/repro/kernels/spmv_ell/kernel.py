@@ -152,6 +152,7 @@ def ell_partials_masked(
             functools.partial(_masked_kernel, combine),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tr), msgs.dtype),
+            name="ell_partials_masked",
             interpret=not kernels.pallas_compiled(),
         )(tw, idx, valid, tab)
         return out.reshape(n_tiles * tr)
@@ -225,6 +226,7 @@ def ell_partials_ragged(
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n_lanes, n_tiles, 1, tr),
                                            msgs.dtype),
+            name="ell_partials_ragged",
             interpret=not kernels.pallas_compiled(),
         )(tw, combine_ids, idx, valid, tab)
         return out.reshape(n_lanes, n_tiles * tr)
@@ -268,6 +270,7 @@ def ell_partials_sentinel(
             functools.partial(_sentinel_kernel, combine),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tr), msgs_ext.dtype),
+            name="ell_partials_sentinel",
             interpret=not kernels.pallas_compiled(),
         )(tw, idx, tab)
         return out.reshape(n_tiles * tr)

@@ -307,55 +307,46 @@ def _mesh_put(mesh, x, *logical):
     return jax.device_put(x, NamedSharding(mesh, graph_ctx(mesh).spec(*logical)))
 
 
+def ragged_lanes_put(lanes_host, *, mesh=None):
+    """Copy a :func:`~repro.core.csr.ragged_lane_concat` result to device:
+    the lane side of a ragged launch.  With ``mesh`` the lane matrix goes
+    straight to its vertex shards and the combine ids are replicated."""
+    msgs_all, cids, combines_set, slices = lanes_host
+    if mesh is None:
+        msgs_d, cids_d = jnp.asarray(msgs_all), jnp.asarray(cids)
+    else:
+        msgs_d = _mesh_put(mesh, msgs_all, "lane", "vertex")
+        cids_d = _mesh_put(mesh, cids, "lane")
+    return {"msgs": msgs_d, "cids": cids_d, "combines": combines_set,
+            "slices": slices}
+
+
 def ragged_stage_lanes(msgs_by_group, combines: Sequence[str], n_pad_v: int,
                        *, mesh=None):
     """Stage the lane side of a ragged launch to device ONCE.
 
     Lane values are fixed within a sweep iteration, so the executor caches
     this across shard batches — the per-group pad+copy the multi path pays
-    on every flush is paid once per iteration instead.  With ``mesh`` the
-    lane matrix goes straight to its vertex shards and the combine ids are
-    replicated.
+    on every flush is paid once per iteration instead.
     """
-    msgs_all, cids, combines_set, slices = ragged_lane_concat(
-        msgs_by_group, combines, n_cols=n_pad_v
+    return ragged_lanes_put(
+        ragged_lane_concat(msgs_by_group, combines, n_cols=n_pad_v),
+        mesh=mesh,
     )
-    if mesh is None:
-        msgs_d, cids_d = jnp.asarray(msgs_all), jnp.asarray(cids)
-    else:
-        msgs_d = _mesh_put(mesh, msgs_all, "lane", "vertex")
-        cids_d = _mesh_put(mesh, cids, "lane")
-    return {
-        "msgs": msgs_d,
-        "cids": cids_d,
-        "combines": combines_set,
-        "slices": slices,
-        "k_total": int(sum(int(m.shape[0]) for m in msgs_by_group)),
-        "k_pad": int(msgs_all.shape[0]),
-    }
 
 
-def ragged_dispatch(ells: Sequence[EllShard], lane_ctx):
-    """Launch ONE ragged update for a shard batch.
+def ragged_launch(batch, staged, lane_ctx):
+    """Launch ONE ragged update for a shard batch whose ``(idx, mask, seg,
+    tile_window)`` are already on device (``staged``).
 
-    Returns ``(batch, acc)`` with ``acc`` an *unforced* device array, so
-    the caller can stage the next batch's host decode while this launch is
-    in flight (the double-buffer protocol, DESIGN.md §14)."""
-    batch, idx, mask, seg, tw = _prep_batch(ells)
-    acc = _update_lanes_ragged_jit(
-        jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(seg),
-        jnp.asarray(tw), lane_ctx["cids"], lane_ctx["msgs"],
+    Returns the accumulator *unforced*, so the caller can stage the next
+    batch's host decode while this launch is in flight (the double-buffer
+    protocol, DESIGN.md §14)."""
+    return _update_lanes_ragged_jit(
+        *staged, lane_ctx["cids"], lane_ctx["msgs"],
         window=batch.window, tr=batch.tr, rows=next_pow2(batch.rows_total),
         combines=lane_ctx["combines"],
     )
-    return batch, acc
-
-
-def ragged_collect(batch, acc, group_slices) -> List[List[np.ndarray]]:
-    """Force a ragged accumulator and slice it back per group per shard —
-    the same list-of-lists shape :func:`ell_update_lanes_multi` returns."""
-    acc = np.asarray(acc)  # blocks until the launch lands
-    return [batch.split(acc[sl]) for sl in group_slices]
 
 
 def ell_update_lanes_ragged(
@@ -384,8 +375,11 @@ def ell_update_lanes_ragged(
         return [[] for _ in msgs_by_group]
     n_pad_v = ells[0].num_windows * ells[0].window
     lane_ctx = ragged_stage_lanes(msgs_by_group, combines, n_pad_v)
-    batch, acc = ragged_dispatch(ells, lane_ctx)
-    return ragged_collect(batch, acc, lane_ctx["slices"])
+    batch, idx, mask, seg, tw = _prep_batch(ells)
+    acc = np.asarray(ragged_launch(
+        batch, tuple(jnp.asarray(x) for x in (idx, mask, seg, tw)), lane_ctx
+    ))
+    return [batch.split(acc[sl]) for sl in lane_ctx["slices"]]
 
 
 #: logical axes of the stacked per-device ELL arrays a mesh step consumes:
@@ -478,18 +472,18 @@ def _mesh_lanes_jit(mesh, backend, window, tr, rows, combine):
     return _mesh_jit(mesh, step, _DEVICE_AXES + (("lane", "vertex"),))
 
 
-def _stage_device_batches(device_ells, mesh):
+def pack_device_batches(device_ells, n_dev: int):
     """Concatenate every device's shard batch with the single-device
-    :func:`_prep_batch` discipline, pad them to COMMON (pow2-bucketed)
-    shapes so the round is one SPMD program, and stage the stacked
-    ``[D, ...]`` arrays straight onto their devices.  The common padding is
-    the usual identity padding, so each shard's accumulator is bitwise what
-    :func:`ell_update_lanes_batched` computes for its device's batch alone.
+    :func:`_prep_batch` discipline and pad them to COMMON (pow2-bucketed)
+    shapes, so the round is one SPMD program — host work only.  The common
+    padding is the usual identity padding, so each shard's accumulator is
+    bitwise what :func:`ell_update_lanes_batched` computes for its device's
+    batch alone.
 
-    Returns ``(batches, staged, first, rows_pad)``, or None when every
+    Returns ``(batches, arrays, first, rows_pad)`` with ``arrays`` the
+    stacked ``[D, ...]`` idx, mask, seg and tile_window, or None when every
     device's list is empty.
     """
-    n_dev = int(mesh.devices.size)
     if len(device_ells) != n_dev:
         raise ValueError(
             f"device_ells has {len(device_ells)} slots for a {n_dev}-device mesh"
@@ -515,11 +509,23 @@ def _stage_device_batches(device_ells, mesh):
             idx, mask, seg, tw, idx.shape[0], tr, n_ell_pad
         )
         idx_all[d], mask_all[d], seg_all[d], tw_all[d] = idx, mask, seg, tw
-    staged = tuple(
-        _mesh_put(mesh, x, *ax)
-        for x, ax in zip((idx_all, mask_all, seg_all, tw_all), _DEVICE_AXES)
-    )
-    return batches, staged, first, rows_pad
+    return batches, (idx_all, mask_all, seg_all, tw_all), first, rows_pad
+
+
+def put_device_batches(arrays, mesh):
+    """Stage :func:`pack_device_batches`' stacked arrays straight onto
+    their devices (device ``d``'s block lands on device ``d``)."""
+    return tuple(_mesh_put(mesh, x, *ax) for x, ax in zip(arrays, _DEVICE_AXES))
+
+
+def _stage_device_batches(device_ells, mesh):
+    """:func:`pack_device_batches` then :func:`put_device_batches`; returns
+    ``(batches, staged, first, rows_pad)`` or None."""
+    packed = pack_device_batches(device_ells, int(mesh.devices.size))
+    if packed is None:
+        return None
+    batches, arrays, first, rows_pad = packed
+    return batches, put_device_batches(arrays, mesh), first, rows_pad
 
 
 def ell_update_lanes_mesh_multi(
@@ -534,7 +540,7 @@ def ell_update_lanes_mesh_multi(
 
     ``device_ells[d]`` holds the shards device ``d`` owns this round (the
     host read each of them ONCE; empty lists idle their device through the
-    SPMD program); see :func:`_stage_device_batches` for the padding.
+    SPMD program); see :func:`pack_device_batches` for the padding.
 
     Returns ``(accs_by_group, touched_by_group)`` where
     ``accs_by_group[g][d]`` lists per-shard ``[K_g, rows]`` accumulators
@@ -620,42 +626,40 @@ def _mesh_lanes_ragged_jit(mesh, backend, window, tr, rows, combines):
     )
 
 
+def mesh_lane_cols(n_pad_v: int, mesh) -> int:
+    """Lane-state columns under ``mesh``: the window-padded vertex count,
+    padded again to a multiple of the device count so the vertex axis
+    shards evenly (the tail past ``n_pad_v`` is never addressed by a valid
+    slot)."""
+    n_dev = int(mesh.devices.size)
+    return -(-n_pad_v // n_dev) * n_dev
+
+
 def mesh_ragged_stage_lanes(msgs_by_group, combines: Sequence[str],
                             n_pad_v: int, mesh):
-    """Mesh variant of :func:`ragged_stage_lanes`: the vertex axis is
-    additionally padded to a multiple of the device count so it shards
-    evenly (the tail past ``n_pad_v`` is never addressed by a valid slot),
-    and the lane matrix is staged straight onto its vertex shards."""
-    n_dev = int(mesh.devices.size)
-    n_pad_dev = -(-n_pad_v // n_dev) * n_dev
-    return ragged_stage_lanes(msgs_by_group, combines, n_pad_dev, mesh=mesh)
+    """Mesh variant of :func:`ragged_stage_lanes`: the lane matrix, at
+    :func:`mesh_lane_cols` columns, is staged straight onto its vertex
+    shards."""
+    return ragged_stage_lanes(msgs_by_group, combines,
+                              mesh_lane_cols(n_pad_v, mesh), mesh=mesh)
 
 
-def mesh_ragged_dispatch(
-    device_ells: Sequence[Sequence[EllShard]],  # [D] lists, device order
-    lane_ctx,
-    *,
-    mesh,
-    backend: str = "pallas",
-):
-    """Launch ONE SPMD step covering every group for this device round.
+def mesh_ragged_launch(batches, staged, first, rows_pad, lane_ctx, *, mesh,
+                       backend: str = "pallas"):
+    """Launch ONE SPMD step covering every group for a staged device round
+    (:func:`pack_device_batches` + :func:`put_device_batches`).
 
     Returns an opaque handle for :func:`mesh_ragged_collect`; the
     accumulator is left unforced so the caller can stage the next round's
-    host decode while the step is in flight.  ``None`` when every device's
-    shard list is empty.
+    host decode while the step is in flight.
     """
-    staged_round = _stage_device_batches(device_ells, mesh)
-    if staged_round is None:
-        return None
-    batches, staged, first, rows_pad = staged_round
     fn = _mesh_lanes_ragged_jit(
         mesh, backend, first.window, first.tr, rows_pad, lane_ctx["combines"]
     )
     acc_all, touched = fn(*staged, lane_ctx["cids"], lane_ctx["msgs"])
     return {
         "batches": batches,
-        "n_dev": len(device_ells),
+        "n_dev": int(mesh.devices.size),
         "acc": acc_all,
         "touched": touched,
         "slices": lane_ctx["slices"],
@@ -706,7 +710,8 @@ def ell_update_lanes_mesh_ragged(
     lane_ctx = mesh_ragged_stage_lanes(
         msgs_by_group, combines, first.num_windows * first.window, mesh
     )
-    handle = mesh_ragged_dispatch(
-        device_ells, lane_ctx, mesh=mesh, backend=backend
+    handle = mesh_ragged_launch(
+        *_stage_device_batches(device_ells, mesh), lane_ctx, mesh=mesh,
+        backend=backend,
     )
     return mesh_ragged_collect(handle)

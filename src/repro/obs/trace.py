@@ -16,17 +16,28 @@ Disabled-by-default discipline
 read one module global. When no tracer is installed they return a shared
 no-op context manager / return immediately — the cost at every call site is
 a global load, a ``None`` check, and (for spans) entering a ``__slots__``
-singleton. Tier-1 timings therefore do not change when tracing is off; the
-``fig_obs`` benchmark section measures this cost per call site and asserts
-the aggregate stays under the 5% overhead budget (DESIGN.md §11).
+singleton. The benchmark's untraced runs measure what that costs end to
+end. Span attributes that take work to compute are set behind
+``active() is not None``, since keyword arguments are evaluated either way.
+
+Compiles
+--------
+The first :func:`install` (or :func:`tracing`) in a process that has
+imported JAX registers one process-wide ``jax.monitoring`` duration
+listener. While a tracer is installed it records each backend compile as
+a ``jax.compile`` span on the compiling thread, ending when JAX reports it
+and lasting the reported duration, so a compile sits under the span that
+caused it; with no tracer installed the listener returns at once.
 
 Span taxonomy (DESIGN.md §11 has the full table)::
 
     service.admit / service.fusion_set / service.retire / service.publish
-    sweep.plan / sweep.iter / batch.form
+    sweep.plan / sweep.iter / sweep.prepare / sweep.commit / batch.form
     shard.load / shard.wait / store.read / store.write
     cache.get / cache.put / overlay.merge / compact.shard
-    exec.dispatch / vsw.run / vsw.iter / mesh.build_device_graph
+    exec.dispatch (exec.stage / exec.put / exec.launch)
+    exec.collect (exec.wait) / jax.compile
+    vsw.run / vsw.iter / mesh.build_device_graph
 
 Events are recorded as ``perf_counter_ns`` intervals and exported with
 microsecond timestamps relative to the tracer's epoch, so traces from one
@@ -37,6 +48,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -115,9 +127,36 @@ def publish_drops(registry: Any) -> int:
     return d
 
 
+#: JAX's monitoring event for one backend compile (its duration in seconds).
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener = False
+
+
+def _on_jax_duration(event: str, duration: float, **_: Any) -> None:
+    t = _ACTIVE
+    if t is None or event != COMPILE_EVENT:
+        return
+    dur = int(duration * 1e9)
+    t._ring().push(("X", "jax.compile", time.perf_counter_ns() - dur, dur,
+                    None))
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_on_jax_duration` once per process, and only where
+    JAX is already imported (tracing never imports it)."""
+    global _compile_listener
+    if _compile_listener or "jax" not in sys.modules:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _compile_listener = True
+
+
 def install(tracer: "Tracer") -> "Tracer":
     """Install `tracer` as the process-wide active tracer."""
     global _ACTIVE
+    _listen_for_compiles()
     _ACTIVE = tracer
     return tracer
 
@@ -133,6 +172,7 @@ def tracing(tracer: Optional["Tracer"] = None) -> Iterator["Tracer"]:
     """Context manager: install a tracer for the block, restore on exit."""
     t = tracer if tracer is not None else Tracer()
     global _ACTIVE
+    _listen_for_compiles()
     prev = _ACTIVE
     _ACTIVE = t
     try:

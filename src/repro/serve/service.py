@@ -44,7 +44,7 @@ from repro.obs.metrics import MetricsRegistry
 
 from .batcher import LaneBatcher
 from .session import SessionCache
-from .sweep import FusedSweep, LaneResult, LaneSeed
+from .sweep import FusedSweep, LaneResult, LaneSeed, SweepIterStats
 
 __all__ = ["GraphService", "QueryResult", "ServiceOverloaded"]
 
@@ -379,7 +379,8 @@ class GraphService:
             future=fut,
             t_submit=t0,
         )
-        with trace.span("service.admit", program=program, source=source):
+        with trace.span("service.admit", program=program, source=source,
+                        query=entry.request_id):
             with self._cond:
                 if self._closed:
                     raise RuntimeError("GraphService is closed")
@@ -546,7 +547,7 @@ class GraphService:
             now = time.perf_counter()
             with trace.span(
                 "service.retire", program=p.program, source=p.source,
-                group=res.group,
+                group=res.group, query=p.request_id,
             ):
                 qr = QueryResult(
                     request_id=p.request_id,
@@ -591,6 +592,14 @@ class GraphService:
             ]
             for g in groups
         ]
+        def on_iter(st: SweepIterStats) -> None:
+            # Per iteration, not per fusion set: a closed loop keeps one
+            # fusion set alive for as long as its callers send queries.
+            self.metrics.ingest(st)
+            self.metrics.histogram("stage.load_s").record(st.load_total_s)
+            self.metrics.histogram("stage.load_wait_s").record(st.load_wait_s)
+            self.metrics.histogram("stage.exec_s").record(st.exec_s)
+
         sweep = FusedSweep(
             self.engine,
             batch_shards=self.batch_shards,
@@ -604,7 +613,8 @@ class GraphService:
                 groups=n_groups,
                 lanes=sum(len(g) for g in groups),
             ):
-                sweep.run(seed_groups, backfill=backfill, on_retire=on_retire)
+                sweep.run(seed_groups, backfill=backfill, on_retire=on_retire,
+                          on_iter=on_iter)
         except BaseException as exc:  # propagate to every unresolved caller
             if isinstance(exc, ShardLoadError):
                 # Prefetch failures are a typed, SLO-visible error class.
@@ -613,16 +623,6 @@ class GraphService:
                 if p.request_id not in resolved and not p.future.done():
                     p.future.set_exception(exc)
         finally:
-            # Absorb the sweep's per-iteration stats: conservation
-            # identities (incl. the mesh device splits) get declared per
-            # iteration and stage-timing histograms feed metrics_snapshot.
-            for st in sweep.iter_stats:
-                self.metrics.ingest(st)
-                self.metrics.histogram("stage.load_s").record(st.load_total_s)
-                self.metrics.histogram("stage.load_wait_s").record(
-                    st.load_wait_s
-                )
-                self.metrics.histogram("stage.exec_s").record(st.exec_s)
             with self._cond:
                 self._sweeps += 1
                 if n_groups > 1:

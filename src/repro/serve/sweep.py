@@ -358,6 +358,7 @@ class FusedSweep:
         *,
         backfill: Optional[Callable[[int, int], Sequence[LaneSeed]]] = None,
         on_retire: Optional[Callable[[LaneResult], None]] = None,
+        on_iter: Optional[Callable[[SweepIterStats], None]] = None,
     ) -> List[LaneResult]:
         """Sweep until every group's lanes have retired and ``backfill``
         is dry.
@@ -367,7 +368,16 @@ class FusedSweep:
         algebra.  ``backfill(g, n_free)`` is called whenever group ``g``
         has free slots; it may return up to ``n_free`` new seeds (same
         combine algebra) which start their own iteration 0 mid-sweep.
-        ``on_retire`` fires the moment a lane finishes.
+        ``on_retire`` fires the moment a lane finishes.  ``on_iter``
+        receives each iteration's :class:`SweepIterStats` as the iteration
+        ends, in place of :attr:`iter_stats` (a sweep that lives as long as
+        its traffic then keeps no per-iteration history).
+
+        Each ``sweep.iter`` span holds, in order: ``sweep.prepare`` (active
+        sets, lane masks, messages, the carried values), ``sweep.plan``,
+        per shard ``shard.wait`` and the executor's ``exec.*`` spans, a
+        ``sweep.commit`` per applied shard result and one for the
+        iteration's attribution, advance, retirement and backfill.
         """
         results: List[LaneResult] = []
 
@@ -416,34 +426,35 @@ class FusedSweep:
             while any(t.live.any() for t in tables):
                 with trace.span("sweep.iter", iteration=it) as it_sp:
                     t0 = time.perf_counter()
-                    io0 = engine.store.io.snapshot()
-                    pstats.reset()
-                    xstats.reset()
+                    with trace.span("sweep.prepare"):
+                        io0 = engine.store.io.snapshot()
+                        pstats.reset()
+                        xstats.reset()
 
-                    group_live = [t.live_slots() for t in tables]
-                    total_live = int(sum(len(sl) for sl in group_live))
-                    n_groups_live = sum(1 for sl in group_live if len(sl))
-                    union_any = np.zeros(n, dtype=bool)
-                    for t, sl in zip(tables, group_live):
-                        if len(sl):
-                            union_any |= t.active[sl].any(axis=0)
-                    union_ids = np.flatnonzero(union_any).astype(np.int64)
-                    lane_active = None
-                    if self.lane_selective and total_live > 1:
-                        lane_active = [
-                            np.flatnonzero(t.active[k]).astype(np.int64)
+                        group_live = [t.live_slots() for t in tables]
+                        total_live = int(sum(len(sl) for sl in group_live))
+                        n_groups_live = sum(1 for sl in group_live if len(sl))
+                        union_any = np.zeros(n, dtype=bool)
+                        for t, sl in zip(tables, group_live):
+                            if len(sl):
+                                union_any |= t.active[sl].any(axis=0)
+                        union_ids = np.flatnonzero(union_any).astype(np.int64)
+                        lane_active = None
+                        if self.lane_selective and total_live > 1:
+                            lane_active = [
+                                np.flatnonzero(t.active[k]).astype(np.int64)
+                                for t, sl in zip(tables, group_live)
+                                for k in sl
+                            ]
+                        msgs = [
+                            t.messages(meta.out_deg) if len(sl) else None
                             for t, sl in zip(tables, group_live)
-                            for k in sl
                         ]
+                        # carried for skipped shards / masked lanes / dead rows
+                        dst = [t.vals.copy() for t in tables]
                     plan = engine.scheduler.plan(
                         union_ids, lane_active=lane_active
                     )
-                    msgs = [
-                        t.messages(meta.out_deg) if len(sl) else None
-                        for t, sl in zip(tables, group_live)
-                    ]
-                    # carried for skipped shards / masked lanes / dead rows
-                    dst = [t.vals.copy() for t in tables]
 
                     loaded = engine.pipeline.iter_shards(
                         plan.shards, stats=pstats
@@ -456,13 +467,17 @@ class FusedSweep:
                                 for m, t in zip(msgs, tables)
                             ]
                             for gi, res in self.executor.run_groups(
-                                loaded, groups_args, xstats
+                                loaded, groups_args, xstats,
+                                lanes_live=total_live,
                             ):
-                                sl = group_live[gi]
-                                acc = np.asarray(res.acc, dtype=np.float32)[sl]
-                                tables[gi].apply_rows(
-                                    acc, sl, res.v0, res.v1, dst[gi]
-                                )
+                                with trace.span("sweep.commit"):
+                                    sl = group_live[gi]
+                                    acc = np.asarray(
+                                        res.acc, dtype=np.float32
+                                    )[sl]
+                                    tables[gi].apply_rows(
+                                        acc, sl, res.v0, res.v1, dst[gi]
+                                    )
                         else:
                             rows_skipped = self._run_masked(
                                 plan, loaded, tables, group_live, msgs, dst,
@@ -475,87 +490,97 @@ class FusedSweep:
                         # loader threads and no stale queue entries.
                         loaded.close()
 
-                    # -------------------------------- commit + attribution
-                    dio = engine.store.io - io0
-                    shares = plan.lane_shares(total_live)
-                    bytes_per_load = (
-                        dio.bytes_read / plan.num_planned if plan.num_planned
-                        else 0.0
-                    )
-                    offset = 0
-                    for gi, (t, sl) in enumerate(zip(tables, group_live)):
-                        if not len(sl):
-                            continue
-                        t.attribute(
-                            shares[offset:offset + len(sl)], bytes_per_load
+                    with trace.span("sweep.commit"):
+                        st = self._commit(
+                            it, t0, plan, tables, group_live, dst, io0,
+                            pstats, xstats, rows_skipped, n_groups_live,
+                            emit, backfill,
                         )
-                        offset += len(sl)
-                        t.advance(dst[gi])
-
-                    # ------------------------------- retirement + backfill
-                    retired = sum(t.retire(emit) for t in tables)
-                    backfilled = 0
-                    if backfill is not None:
-                        for t in tables:
-                            while True:
-                                n_free = t.free_count()
-                                if n_free == 0:
-                                    break
-                                got = list(backfill(t.group, n_free))
-                                if not got:
-                                    break
-                                for seed in got:
-                                    res = t.admit(seed)
-                                    if res is not None:
-                                        emit(res)  # zero-budget, slot free
-                                    else:
-                                        backfilled += 1
-
-                    dev_shards = dev_disp = dev_bytes = ()
-                    if plan.device_shards is not None:
-                        dev_shards = tuple(len(g) for g in plan.device_shards)
-                        dev_bytes = tuple(
-                            len(g) * bytes_per_load
-                            for g in plan.device_shards
+                        if on_iter is None:
+                            self.iter_stats.append(st)
+                        else:
+                            on_iter(st)
+                        it_sp.set(
+                            shards=st.shards_processed,
+                            live_lanes=st.live_lanes,
+                            groups=st.groups,
+                            retired=st.retired,
+                            backfilled=st.backfilled,
                         )
-                        dev_disp = tuple(
-                            xstats.device_dispatches.get(d, 0)
-                            for d in range(len(plan.device_shards))
-                        )
-
-                    self.iter_stats.append(
-                        SweepIterStats(
-                            iteration=it,
-                            live_lanes=total_live,
-                            shards_processed=plan.num_planned,
-                            shards_skipped=plan.num_skipped,
-                            bytes_read=dio.bytes_read,
-                            selective_on=plan.selective_on,
-                            retired=retired,
-                            backfilled=backfilled,
-                            time_s=time.perf_counter() - t0,
-                            lane_rows_skipped=rows_skipped,
-                            load_total_s=pstats.load_total_s,
-                            load_wait_s=pstats.wait_s,
-                            exec_s=xstats.exec_s,
-                            groups=n_groups_live,
-                            dispatches=xstats.dispatches,
-                            batches=xstats.batches,
-                            overlap_s=xstats.overlap_s,
-                            device_shards=dev_shards,
-                            device_dispatches=dev_disp,
-                            device_bytes=dev_bytes,
-                        )
-                    )
-                    it_sp.set(
-                        shards=plan.num_planned,
-                        live_lanes=total_live,
-                        groups=n_groups_live,
-                        retired=retired,
-                        backfilled=backfilled,
-                    )
                 it += 1
         return results
+
+    def _commit(self, it, t0, plan, tables, group_live, dst, io0, pstats,
+                xstats, rows_skipped, n_groups_live, emit, backfill):
+        """End one iteration: attribute its I/O, advance every live table,
+        retire finished lanes, backfill freed slots, and return the
+        iteration's :class:`SweepIterStats`."""
+        engine = self.engine
+        total_live = int(sum(len(sl) for sl in group_live))
+        dio = engine.store.io - io0
+        shares = plan.lane_shares(total_live)
+        bytes_per_load = (
+            dio.bytes_read / plan.num_planned if plan.num_planned else 0.0
+        )
+        offset = 0
+        for gi, (t, sl) in enumerate(zip(tables, group_live)):
+            if not len(sl):
+                continue
+            t.attribute(shares[offset:offset + len(sl)], bytes_per_load)
+            offset += len(sl)
+            t.advance(dst[gi])
+
+        # ------------------------------------------ retirement + backfill
+        retired = sum(t.retire(emit) for t in tables)
+        backfilled = 0
+        if backfill is not None:
+            for t in tables:
+                while True:
+                    n_free = t.free_count()
+                    if n_free == 0:
+                        break
+                    got = list(backfill(t.group, n_free))
+                    if not got:
+                        break
+                    for seed in got:
+                        res = t.admit(seed)
+                        if res is not None:
+                            emit(res)  # zero-budget, slot free
+                        else:
+                            backfilled += 1
+
+        dev_shards = dev_disp = dev_bytes = ()
+        if plan.device_shards is not None:
+            dev_shards = tuple(len(g) for g in plan.device_shards)
+            dev_bytes = tuple(
+                len(g) * bytes_per_load for g in plan.device_shards
+            )
+            dev_disp = tuple(
+                xstats.device_dispatches.get(d, 0)
+                for d in range(len(plan.device_shards))
+            )
+        return SweepIterStats(
+            iteration=it,
+            live_lanes=total_live,
+            shards_processed=plan.num_planned,
+            shards_skipped=plan.num_skipped,
+            bytes_read=dio.bytes_read,
+            selective_on=plan.selective_on,
+            retired=retired,
+            backfilled=backfilled,
+            time_s=time.perf_counter() - t0,
+            lane_rows_skipped=rows_skipped,
+            load_total_s=pstats.load_total_s,
+            load_wait_s=pstats.wait_s,
+            exec_s=xstats.exec_s,
+            groups=n_groups_live,
+            dispatches=xstats.dispatches,
+            batches=xstats.batches,
+            overlap_s=xstats.overlap_s,
+            device_shards=dev_shards,
+            device_dispatches=dev_disp,
+            device_bytes=dev_bytes,
+        )
 
     # ------------------------------------------------- lane-masked dispatch
     def _run_masked(
@@ -597,31 +622,37 @@ class FusedSweep:
             groups_args: List[Optional[Tuple[np.ndarray, str]]] = []
             group_slots: List[Optional[np.ndarray]] = []
             offset = 0
-            for gi, (t, sl, m) in enumerate(zip(tables, group_live, msgs)):
-                sub = buf_mask[offset:offset + len(sl)]
-                offset += len(sl)
-                dsl = sl[sub] if len(sl) else sl
-                rows_skipped += (len(sl) - len(dsl)) * len(buf)
-                if not len(dsl):
-                    groups_args.append(None)
-                    group_slots.append(None)
-                    continue
-                key = (gi, dsl.tobytes())
-                subm = staged.get(key)
-                if subm is None:
-                    k = len(dsl)
-                    cap_sub = pad_lanes(k) if self.pad_pow2 else k
-                    subm = np.zeros((cap_sub, m.shape[1]), dtype=m.dtype)
-                    subm[:k] = m[dsl]
-                    staged[key] = subm
-                groups_args.append((subm, t.combine))
-                group_slots.append(dsl)
+            with trace.span("sweep.prepare"):
+                for gi, (t, sl, m) in enumerate(
+                    zip(tables, group_live, msgs)
+                ):
+                    sub = buf_mask[offset:offset + len(sl)]
+                    offset += len(sl)
+                    dsl = sl[sub] if len(sl) else sl
+                    rows_skipped += (len(sl) - len(dsl)) * len(buf)
+                    if not len(dsl):
+                        groups_args.append(None)
+                        group_slots.append(None)
+                        continue
+                    key = (gi, dsl.tobytes())
+                    subm = staged.get(key)
+                    if subm is None:
+                        k = len(dsl)
+                        cap_sub = pad_lanes(k) if self.pad_pow2 else k
+                        subm = np.zeros((cap_sub, m.shape[1]), dtype=m.dtype)
+                        subm[:k] = m[dsl]
+                        staged[key] = subm
+                    groups_args.append((subm, t.combine))
+                    group_slots.append(dsl)
+            lanes_live = sum(len(d) for d in group_slots if d is not None)
             for gi, res in self.executor.run_groups(
-                iter(buf), groups_args, xstats
+                iter(buf), groups_args, xstats,
+                masked=True, lanes_live=lanes_live,
             ):
-                dsl = group_slots[gi]
-                acc = np.asarray(res.acc, dtype=np.float32)[: len(dsl)]
-                tables[gi].apply_rows(acc, dsl, res.v0, res.v1, dst[gi])
+                with trace.span("sweep.commit"):
+                    dsl = group_slots[gi]
+                    acc = np.asarray(res.acc, dtype=np.float32)[: len(dsl)]
+                    tables[gi].apply_rows(acc, dsl, res.v0, res.v1, dst[gi])
             buf, buf_mask = [], None
 
         for ls in loaded:
