@@ -480,12 +480,13 @@ GroupDispatch = Optional[Tuple[np.ndarray, str]]
 
 
 def _dispatch_counters(ells, slots: int, masked: bool, lanes_live: int,
-                       lanes_pad: int, copied) -> Dict:
+                       lanes_pad: int, copied, grid_steps=None) -> Dict:
     """The attributes of one ragged ``exec.dispatch`` span: the work it
     launched (real edges from shard metadata, ELL slots after row
-    bucketing, lanes carrying a query against lanes launched) and the bytes
-    it copied to the device.  Computed only while a tracer is installed."""
-    return {
+    bucketing, lanes carrying a query against lanes launched), the bytes
+    it copied to the device, and for a Pallas launch its kernel's grid
+    steps.  Computed only while a tracer is installed."""
+    out = {
         "masked": masked,
         "edges": sum(int(e.nnz) for e in ells),
         "slots": int(slots),
@@ -493,6 +494,20 @@ def _dispatch_counters(ells, slots: int, masked: bool, lanes_live: int,
         "lanes_pad": int(lanes_pad),
         "h2d_bytes": sum(int(x.nbytes) for x in copied),
     }
+    if grid_steps is not None:
+        out["grid_steps"] = int(grid_steps)
+    return out
+
+
+def _ragged_grid_steps(backend: str, batch, n_ell_pad: int, msgs):
+    """Grid steps of the Pallas ragged kernel for one launch; None for a
+    backend that runs no Pallas grid."""
+    if backend != "pallas":
+        return None
+    from repro.kernels.spmv_ell.kernel import ragged_grid_steps
+
+    return ragged_grid_steps(int(msgs.shape[0]), n_ell_pad // batch.tr,
+                             batch.window, msgs.dtype.itemsize)
 
 
 # --------------------------------------------------------------------------
@@ -785,6 +800,8 @@ class BatchedEllExecutor:
                         int(lane_ctx["msgs"].shape[0]),
                         (idx, mask, seg, tw) + (lanes_host[:2] if lanes_host
                                                 else ()),
+                        _ragged_grid_steps(self.backend_name, batch,
+                                           n_ell_pad, lane_ctx["msgs"]),
                     ))
             if stats is not None:
                 stats.dispatches += 1

@@ -85,13 +85,19 @@ def test_sentinel_compiles_for_v5e(one_chip):
     _check(c)
 
 
-def test_ragged_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("lanes,n_ell,n_windows,lane_blocks", [
+    (24, 32768, 8, 1),   # the serving cell's launch: one block of 24 lanes
+    (96, N_ELL, N_WINDOWS, 2),  # past the VMEM budget: two blocks of 48
+], ids=["serve-cell", "two-lane-blocks"])
+def test_ragged_compiles_for_v5e(one_chip, lanes, n_ell, n_windows,
+                                 lane_blocks):
     from repro.kernels.spmv_ell import kernel as Kn
 
+    assert lanes // Kn.ragged_lane_block(lanes, W) == lane_blocks
     c = Kn.ell_partials_ragged.lower(
-        one_chip((N_ELL, K), jnp.int16), one_chip((N_ELL, K), jnp.bool_),
-        one_chip((N_ELL // TR,), jnp.int32), one_chip((LANES,), jnp.int32),
-        one_chip((LANES, N_WINDOWS * W), jnp.float32),
+        one_chip((n_ell, K), jnp.int16), one_chip((n_ell, K), jnp.bool_),
+        one_chip((n_ell // TR,), jnp.int32), one_chip((lanes,), jnp.int32),
+        one_chip((lanes, n_windows * W), jnp.float32),
         window=W, tr=TR, combines=COMBINES,
     ).compile()
     _check(c)

@@ -10,6 +10,7 @@ masks off, and lane-selective scheduling that sets them):
 - the dispatch counters add up: real edges over an iteration that planned
   every shard are the graph's edges, slots cover edges, live lanes fit the
   launched lanes, and the launched lanes cover ``ExecStats.ragged_lanes``;
+- a Pallas dispatch counts its kernel's grid steps (lane blocks × tiles);
 - tracing leaves every result bitwise as it was.
 
 Besides: a backend compile is recorded as a ``jax.compile`` span under the
@@ -32,6 +33,7 @@ from repro.obs import Tracer, trace
 from repro.serve import FusedSweep, GraphService, LaneSeed
 
 EXEC_CHILDREN = ["exec.stage", "exec.put", "exec.launch"]
+WINDOW, K, TR = 128, 16, 8
 ITER_CHILDREN = {"sweep.prepare", "sweep.plan", "shard.wait", "exec.dispatch",
                  "exec.collect", "sweep.commit"}
 
@@ -88,8 +90,8 @@ def traced_sweep(request, tmp_path_factory):
     d = tmp_path_factory.mktemp(f"disp-{backend}-{lane_selective}")
     # threshold: the first iterations (a few sources active) plan
     # selectively, so lane-selective scheduling sets lane masks there
-    eng = VSWEngine.from_graph(g, str(d), num_shards=5, window=128, k=16,
-                               backend=backend, batch_shards=2,
+    eng = VSWEngine.from_graph(g, str(d), num_shards=5, window=WINDOW, k=K,
+                               tr=TR, backend=backend, batch_shards=2,
                                threshold=0.05)
     base = FusedSweep(eng, batch_shards=2, lane_selective=lane_selective)
     untraced = {r.token: r for r in base.run(_seeds(not lane_selective))}
@@ -110,6 +112,7 @@ def traced_sweep(request, tmp_path_factory):
     return {"spans": _spans(tr, threading.get_ident()), "untraced": untraced,
             "traced": traced, "ragged_lanes": ragged_lanes[0],
             "masked": lane_selective, "edges": g.num_edges,
+            "backend": backend,
             "num_shards": eng.meta.num_shards, "tracer": tr}
 
 
@@ -167,6 +170,23 @@ def test_dispatch_counters_add_up_e2e(traced_sweep):
         edges = sum(d[3]["edges"] for d in _inside(it, spans)
                     if d[2] == "exec.dispatch")
         assert edges == traced_sweep["edges"]
+
+
+def test_pallas_dispatch_grid_steps_e2e(traced_sweep):
+    """A Pallas ragged dispatch counts its kernel's grid steps: lane blocks
+    times tiles; a jnp dispatch runs no grid and counts none."""
+    from repro.kernels.spmv_ell.kernel import ragged_lane_block
+
+    dispatches = [d[3] for d in _named(traced_sweep["spans"], "exec.dispatch")]
+    assert dispatches
+    for a in dispatches:
+        if traced_sweep["backend"] != "pallas":
+            assert "grid_steps" not in a
+            continue
+        lane_blocks = a["lanes_pad"] // ragged_lane_block(a["lanes_pad"],
+                                                          WINDOW)
+        n_ell_pad = a["slots"] // K
+        assert a["grid_steps"] == lane_blocks * n_ell_pad // TR > 0
 
 
 def test_traced_sweep_results_bitwise_untraced_e2e(traced_sweep):

@@ -155,6 +155,81 @@ def test_ragged_ops_bitwise_vs_multi_e2e(combines):
         [[] for _ in combines]
 
 
+def test_ragged_lane_block_fits_vmem_budget():
+    """Every lane in one block while the double-buffered windows fit the
+    budget, else the largest divisor of the lane count that fits."""
+    from repro.kernels.spmv_ell import kernel as Kn
+
+    w = 16384
+    assert Kn.ragged_lane_block(1, w) == 1
+    assert Kn.ragged_lane_block(24, w) == 24
+    assert Kn.ragged_lane_block(64, w) == 64
+    assert Kn.ragged_lane_block(96, w) == 48
+    assert Kn.ragged_lane_block(65, w) == 13
+    assert Kn.ragged_lane_block(7, 1 << 22) == 1  # nothing fits: one lane
+    assert Kn.ragged_grid_steps(24, 4096, w) == 4096
+    assert Kn.ragged_grid_steps(96, 1024, w) == 2 * 1024
+    for n in range(1, 200):
+        lb = Kn.ragged_lane_block(n, w)
+        assert n % lb == 0 and 2 * lb * w * 4 <= Kn.RAGGED_WINDOW_VMEM
+
+
+@pytest.mark.parametrize("n_lanes,block,rows_per_iter", [
+    (1, None, None), (3, None, None), (24, None, None),  # 3 groups of 8
+    (24, 8, None),  # budget forced down: three lane blocks
+    (6, 4, None),   # largest divisor that fits: two blocks of 3
+    (5, None, 1),   # the gather's rows in a loop of one round each
+])
+def test_ragged_lane_blocks_bitwise_vs_masked_e2e(monkeypatch, n_lanes,
+                                                  block, rows_per_iter):
+    """Each lane of the lane-blocked ragged kernel equals a solo masked
+    launch with that lane's combine; a lane whose arm id matches no arm
+    (every third lane) is a zero row."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.spmv_ell import kernel as Kn
+
+    window, combines = 256, ("min", "sum")
+    forced = block is not None or rows_per_iter is not None
+    if block is not None:
+        monkeypatch.setattr(Kn, "RAGGED_WINDOW_VMEM", 2 * block * window * 4)
+    if rows_per_iter is not None:
+        monkeypatch.setattr(Kn, "ROWS_PER_ITER", rows_per_iter)
+    if forced:
+        jax.clear_caches()  # no trace made under the default constants
+    expect = n_lanes if block is None else max(
+        d for d in range(1, block + 1) if n_lanes % d == 0)
+    assert Kn.ragged_lane_block(n_lanes, window) == expect
+    if block is not None:
+        assert expect < n_lanes
+
+    g = rmat_graph(600, 7000, seed=144)
+    _, shards = preprocess(g, num_shards=1)
+    e = csr_to_ell(shards[0], g.num_vertices, window=window, k=16, tr=8)
+    rng = np.random.default_rng(144)
+    msgs = rng.random((n_lanes, e.num_windows * window)).astype(np.float32)
+    cids = np.arange(n_lanes, dtype=np.int32) % 3  # 2: no arm
+    msgs[cids == 0] = np.where(msgs[cids == 0] > 0.6, np.inf,
+                               msgs[cids == 0])
+    ell = (jnp.asarray(e.ell_idx), jnp.asarray(e.ell_mask),
+           jnp.asarray(e.tile_window))
+    out = np.asarray(Kn.ell_partials_ragged(
+        *ell, jnp.asarray(cids), jnp.asarray(msgs), window=window, tr=8,
+        combines=combines))
+    if forced:
+        jax.clear_caches()
+    assert out.shape == (n_lanes, e.n_tiles * 8)
+    for lane, cid in enumerate(cids):
+        if cid == len(combines):
+            assert np.all(out[lane] == 0.0), lane
+            continue
+        ref = np.asarray(Kn.ell_partials_masked(
+            *ell, jnp.asarray(msgs[lane]), window=window, tr=8,
+            combine=combines[cid]))
+        assert np.array_equal(_norm(out[lane]), _norm(ref)), lane
+
+
 # -------------------------------------------------------- sweep bitwise
 @pytest.mark.parametrize("backend,batch_shards,lane_selective", [
     ("jnp", 1, True), ("jnp", 3, True), ("pallas", 2, True),
