@@ -480,13 +480,13 @@ GroupDispatch = Optional[Tuple[np.ndarray, str]]
 
 
 def _dispatch_counters(ells, slots: int, masked: bool, lanes_live: int,
-                       lanes_pad: int, copied, grid_steps=None) -> Dict:
+                       lanes_pad: int, copied) -> Dict:
     """The attributes of one ragged ``exec.dispatch`` span: the work it
     launched (real edges from shard metadata, ELL slots after row
-    bucketing, lanes carrying a query against lanes launched), the bytes
-    it copied to the device, and for a Pallas launch its kernel's grid
-    steps.  Computed only while a tracer is installed."""
-    out = {
+    bucketing, lanes carrying a query against lanes launched) and the
+    bytes it copied to the device.  Computed only while a tracer is
+    installed."""
+    return {
         "masked": masked,
         "edges": sum(int(e.nnz) for e in ells),
         "slots": int(slots),
@@ -494,20 +494,27 @@ def _dispatch_counters(ells, slots: int, masked: bool, lanes_live: int,
         "lanes_pad": int(lanes_pad),
         "h2d_bytes": sum(int(x.nbytes) for x in copied),
     }
-    if grid_steps is not None:
-        out["grid_steps"] = int(grid_steps)
-    return out
 
 
-def _ragged_grid_steps(backend: str, batch, n_ell_pad: int, msgs):
-    """Grid steps of the Pallas ragged kernel for one launch; None for a
-    backend that runs no Pallas grid."""
+def _ragged_kernel_counters(backend: str, batch, n_ell_pad: int,
+                            msgs) -> Dict:
+    """The Pallas ragged kernel's work in one launch: its grid steps (lane
+    blocks x tiles), the gather rounds each lane runs (``rounds``) and
+    those of every table row of every tile (``rounds_full``, tiles x W /
+    L).  Padding tiles run no rounds, so the batch's own slots give
+    ``rounds``.  Empty for a backend that runs no Pallas grid."""
     if backend != "pallas":
-        return None
-    from repro.kernels.spmv_ell.kernel import ragged_grid_steps
+        return {}
+    from repro.kernels.spmv_ell import kernel as Kn
 
-    return ragged_grid_steps(int(msgs.shape[0]), n_ell_pad // batch.tr,
-                             batch.window, msgs.dtype.itemsize)
+    n_tiles, w = n_ell_pad // batch.tr, batch.window
+    return {
+        "grid_steps": Kn.ragged_grid_steps(int(msgs.shape[0]), n_tiles, w,
+                                           msgs.dtype.itemsize),
+        "rounds": Kn.ragged_rounds(Kn.ragged_rows_read(
+            batch.ell_idx, batch.ell_mask, window=w, tr=batch.tr)),
+        "rounds_full": n_tiles * (w // Kn.table_lanes(w)),
+    }
 
 
 # --------------------------------------------------------------------------
@@ -800,9 +807,8 @@ class BatchedEllExecutor:
                         int(lane_ctx["msgs"].shape[0]),
                         (idx, mask, seg, tw) + (lanes_host[:2] if lanes_host
                                                 else ()),
-                        _ragged_grid_steps(self.backend_name, batch,
-                                           n_ell_pad, lane_ctx["msgs"]),
-                    ))
+                    ), **_ragged_kernel_counters(self.backend_name, batch,
+                                                 n_ell_pad, lane_ctx["msgs"]))
             if stats is not None:
                 stats.dispatches += 1
                 stats.ragged_dispatches += 1

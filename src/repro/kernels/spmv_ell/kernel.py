@@ -36,11 +36,15 @@ Three variants:
   the indices once.  A ``fori_loop`` over groups of up to
   :data:`LANE_GROUP` lanes runs each group's gathers interleaved, round by
   round: a round's lane permute is its slowest op, and a lone lane leaves
-  the permute unit waiting on its loads and selects.  The rounds run
-  :data:`ROWS_PER_ITER` to a loop iteration, which keeps the body, and
-  its compile time, near a lone lane's.  ``LB`` is every lane when their
-  double-buffered windows fit :data:`RAGGED_WINDOW_VMEM`, else the largest
-  divisor of the lane count that fits.
+  the permute unit waiting on its loads and selects.  A tile runs rounds
+  only for the table rows its valid slots read: :func:`ragged_row_table`
+  lists them per tile (a jitted prologue, before the launch), the
+  iteration count arrives by scalar prefetch and the tile's row list as
+  an SMEM block, and the rounds run :data:`ROWS_PER_ITER` to a loop
+  iteration, which keeps the body, and its compile time, near a lone
+  lane's.  A tile with no valid slot runs none.  ``LB`` is every lane
+  when their double-buffered windows fit :data:`RAGGED_WINDOW_VMEM`, else
+  the largest divisor of the lane count that fits.
 """
 
 from __future__ import annotations
@@ -50,6 +54,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -67,10 +72,10 @@ MAX_TILES_PER_CALL = 1 << 17
 #: divisor of the lane block).
 LANE_GROUP = 8
 
-#: Gather rounds per loop iteration of the ragged kernel.  With a group of
-#: lanes interleaved a loop over the rows costs little, and it bounds the
-#: body to compile to ``LANE_GROUP x ROWS_PER_ITER`` gathers.
-ROWS_PER_ITER = 32
+#: Gather rounds per loop iteration of the ragged kernel's row loop.  With
+#: a group of lanes interleaved a loop over the rows costs little, and it
+#: bounds the body to compile to ``LANE_GROUP x ROWS_PER_ITER`` gathers.
+ROWS_PER_ITER = 16
 
 #: VMEM for the double-buffered message windows of one ragged grid step:
 #: 64 lanes at W=16384, well inside a v5e's default scoped VMEM.
@@ -101,7 +106,7 @@ def _split(idx_ref, lanes: int):
     return idx >> (lanes.bit_length() - 1), idx & (lanes - 1)
 
 
-def _gather(tabs, hi: jax.Array, lo: jax.Array, rows_per_iter=None) -> list:
+def _gather(tabs, hi: jax.Array, lo: jax.Array, listed=None) -> list:
     """``table[idx]`` from each resident ``(rows, L)`` window table in
     ``tabs``, for a tile split by :func:`_split`.
 
@@ -109,20 +114,21 @@ def _gather(tabs, hi: jax.Array, lo: jax.Array, rows_per_iter=None) -> list:
     tables' gathers within a round are independent: interleaved, the lane
     permute each one needs (the slowest op of a round) overlaps the
     others' loads and selects, and the tables share the ``hi == j`` mask.
-    The rounds are unrolled (the rows' gathers are independent, so the
-    compiler can overlap them; one round per loop iteration waits out the
-    gather's latency), all of them, or ``rows_per_iter`` per iteration of
-    a loop over the rows, which bounds the body to compile.
-    """
-    rows = tabs[0].shape[0]
-    n = hi.shape[0]
-    step = rows if rows_per_iter is None else _divisor_at_most(
-        rows, rows_per_iter)
 
-    def rounds(c, gs):
+    With ``listed`` None every row gets a round, all unrolled (the rows'
+    gathers are independent, so the compiler can overlap them; one round
+    per loop iteration waits out the gather's latency).  With ``listed =
+    (rows_ref, n_iter)`` only the rows the tile's row list names get one:
+    a loop of ``n_iter`` iterations of ``step`` (:func:`_rows_per_iter`)
+    unrolled rounds each, round ``r`` of iteration ``c`` at row
+    ``rows_ref[0, c * step + r]`` (:func:`ragged_row_table`).  A slot whose
+    row is not listed keeps the zero init.
+    """
+    n = hi.shape[0]
+
+    def rounds(row_ids, gs):
         gs = list(gs)
-        for r in range(step):
-            j = c * step + r
+        for j in row_ids:
             hit = hi == j
             for t, tab in enumerate(tabs):
                 row = jnp.broadcast_to(tab[pl.ds(j, 1), :], (n, tab.shape[1]))
@@ -132,9 +138,15 @@ def _gather(tabs, hi: jax.Array, lo: jax.Array, rows_per_iter=None) -> list:
         return tuple(gs)
 
     gs = tuple(jnp.zeros(hi.shape, t.dtype) for t in tabs)
-    if step == rows:
-        return list(rounds(0, gs))
-    return list(jax.lax.fori_loop(0, rows // step, rounds, gs))
+    if listed is None:
+        return list(rounds(range(tabs[0].shape[0]), gs))
+    rows_ref, n_iter = listed
+    step = _rows_per_iter(tabs[0].shape[0])
+    return list(jax.lax.fori_loop(
+        0, n_iter,
+        lambda c, gs: rounds([rows_ref[0, c * step + r] for r in range(step)],
+                             gs),
+        gs))
 
 
 def _divisor_at_most(n: int, cap: int) -> int:
@@ -142,15 +154,16 @@ def _divisor_at_most(n: int, cap: int) -> int:
     return max(d for d in range(1, min(n, max(cap, 1)) + 1) if n % d == 0)
 
 
-def _over_tile_chunks(call, tile_window, tr, *row_arrays):
-    """``call(tile_window, *row_arrays)`` over chunks of at most
+def _over_tile_chunks(call, tile_arrays, tr, *row_arrays):
+    """``call(*tile_arrays, *row_arrays)`` over chunks of at most
     :data:`MAX_TILES_PER_CALL` tiles, partials concatenated on the last
-    axis.  ``row_arrays`` are indexed by ELL row (``tr`` rows per tile)."""
-    n_tiles = tile_window.shape[0]
+    axis.  ``tile_arrays`` are indexed by tile (``tile_window`` first),
+    ``row_arrays`` by ELL row (``tr`` rows per tile)."""
+    n_tiles = tile_arrays[0].shape[0]
     if n_tiles <= MAX_TILES_PER_CALL:
-        return call(tile_window, *row_arrays)
+        return call(*tile_arrays, *row_arrays)
     return jnp.concatenate([
-        call(tile_window[a:a + MAX_TILES_PER_CALL],
+        call(*(x[a:a + MAX_TILES_PER_CALL] for x in tile_arrays),
              *(x[a * tr:(a + MAX_TILES_PER_CALL) * tr] for x in row_arrays))
         for a in range(0, n_tiles, MAX_TILES_PER_CALL)
     ], axis=-1)
@@ -222,7 +235,7 @@ def ell_partials_masked(
         )(tw, idx, valid, tab)
         return out.reshape(n_tiles * tr)
 
-    return _over_tile_chunks(call, tile_window, tr, ell_idx, ell_valid)
+    return _over_tile_chunks(call, (tile_window,), tr, ell_idx, ell_valid)
 
 
 # --------------------------------------------------------------- ragged
@@ -242,12 +255,86 @@ def ragged_grid_steps(n_lanes: int, n_tiles: int, window: int,
     return n_lanes // ragged_lane_block(n_lanes, window, itemsize) * n_tiles
 
 
-def _ragged_kernel(combines, tile_window_ref, combine_ids_ref, idx_ref,
-                   valid_ref, tab_ref, out_ref):
+def _rows_per_iter(rows: int) -> int:
+    """Rounds per iteration of the ragged kernel's row loop over a window
+    table of ``rows`` rows: :data:`ROWS_PER_ITER`, or the largest divisor of
+    ``rows`` below it."""
+    return _divisor_at_most(rows, ROWS_PER_ITER)
+
+
+def ragged_rows_read(ell_idx, ell_valid, *, window: int, tr: int):
+    """Host (numpy): ``[n_tiles, W / L]`` bool, whether a valid slot of
+    tile ``t`` reads window-table row ``j``; one ``bincount`` over ``t *
+    (W / L) + (idx >> log2(L))`` of the valid slots.  The oracle of
+    :func:`ragged_row_table` and the source of :func:`ragged_rounds`."""
+    lanes = table_lanes(window)
+    rows = window // lanes
+    n_tiles = ell_idx.shape[0] // tr
+    slots = np.flatnonzero(ell_valid)
+    hi = ell_idx.reshape(-1)[slots].astype(np.int64) >> (lanes.bit_length()
+                                                         - 1)
+    return np.bincount(slots // (tr * ell_idx.shape[1]) * rows + hi,
+                       minlength=n_tiles * rows).reshape(n_tiles, rows) > 0
+
+
+def ragged_rounds(rows_read) -> int:
+    """Gather rounds each lane runs in one :func:`ell_partials_ragged`
+    launch, from :func:`ragged_rows_read`: the sum over tiles of ``n_iter``
+    times the rounds per iteration."""
+    step = _rows_per_iter(rows_read.shape[1])
+    return int((-(-rows_read.sum(axis=1) // step)).sum()) * step
+
+
+def ragged_row_table(ell_idx: jax.Array, ell_valid: jax.Array, *,
+                     window: int, tr: int):
+    """The ragged kernel's per-tile row lists, on the device.
+
+    ``tile_rows[t]`` lists, ascending, the window-table rows
+    (``idx >> log2(L)``) that tile ``t``'s valid slots read, padded to
+    whole loop iterations by repeating the last listed row (a repeated
+    round selects the same values again: bitwise harmless);
+    ``n_iter[t] = ceil(count / step)`` (:func:`_rows_per_iter`), 0 for a
+    tile with no valid slot.  Compares and reductions only, no scatter or
+    sort: presence as one bit per row in 32-bit words, OR-reduced over the
+    tile's slots; then entry ``p`` is the number of rows whose running
+    count of listed rows is at most ``p``.  :func:`ragged_rows_read` is
+    the host form of its row sets.
+
+    Returns ``(n_iter [n_tiles] int32, tile_rows [n_tiles, W / L] int32)``.
+    """
+    lanes = table_lanes(window)
+    rows = window // lanes
+    n_tiles = ell_idx.shape[0] // tr
+    hi = (ell_idx.astype(jnp.int32) >> (lanes.bit_length() - 1)).reshape(
+        n_tiles, -1)
+    valid = ell_valid.reshape(n_tiles, -1)
+    # bit (hi & 31) of word (hi >> 5) marks row hi as read
+    word = valid[:, :, None] & (hi[:, :, None] >> 5 == jnp.arange(
+        -(-rows // 32), dtype=jnp.int32))
+    words = jax.lax.reduce(
+        jnp.where(word, jnp.left_shift(jnp.int32(1), hi & 31)[:, :, None], 0),
+        jnp.int32(0), jax.lax.bitwise_or, (1,))
+    present = (words[:, :, None] >> jnp.arange(32, dtype=jnp.int32)
+               & 1).reshape(n_tiles, -1)[:, :rows]
+    # running count of listed rows, in log2(rows) shifted adds (XLA's
+    # cumsum compiles for seconds on the TPU)
+    seen, k = present, 1
+    while k < rows:
+        seen = seen + jnp.pad(seen[:, :-k], ((0, 0), (k, 0)))
+        k *= 2
+    ids = jnp.arange(rows, dtype=jnp.int32)
+    table = (seen[:, None, :] <= ids[:, None]).sum(axis=2, dtype=jnp.int32)
+    last = jnp.max(jnp.where(present > 0, ids, 0), axis=1)
+    step = _rows_per_iter(rows)
+    return (seen[:, -1] + step - 1) // step, jnp.minimum(table, last[:, None])
+
+
+def _ragged_kernel(combines, tile_window_ref, n_iter_ref, combine_ids_ref,
+                   idx_ref, valid_ref, rows_ref, tab_ref, out_ref):
     """One (TR, K) tile for a block of ``LB`` lanes: split the indices
-    once, then per group of lanes gather (their rounds interleaved), and
-    per lane reduce per combine arm and keep the arm that lane's
-    ``combine_id`` selects.
+    once, then per group of lanes gather (their rounds interleaved, over
+    the tile's listed rows only), and per lane reduce per combine arm and
+    keep the arm that lane's ``combine_id`` selects.
 
     ``jnp.where`` returns the selected arm's value bit-for-bit, so each lane
     is op-for-op identical to a solo ``_masked_kernel`` launch with its own
@@ -259,13 +346,14 @@ def _ragged_kernel(combines, tile_window_ref, combine_ids_ref, idx_ref,
     hi, lo = _split(idx_ref, tab_ref.shape[-1])
     valid = valid_ref[...]
     lane0 = pl.program_id(0) * lb
+    listed = (rows_ref, n_iter_ref[pl.program_id(1)])
 
     # A loop over groups, not unrolled: every lane unrolled into one body
     # would multiply the code to compile.
     def lanes(i, carry):
         l0 = i * group
         gs = _gather([tab_ref.at[l0 + a] for a in range(group)], hi, lo,
-                     ROWS_PER_ITER)
+                     listed)
         for a, g in enumerate(gs):
             cid = combine_ids_ref[lane0 + l0 + a]
             out = jnp.zeros((g.shape[0],), g.dtype)
@@ -284,6 +372,8 @@ def ell_partials_ragged(
     ell_idx: jax.Array,  # [n_ell, K] int16/int32 window-local
     ell_valid: jax.Array,  # [n_ell, K] bool
     tile_window: jax.Array,  # [n_tiles] int32
+    n_iter: jax.Array,  # [n_tiles] int32 row-loop iterations per tile
+    tile_rows: jax.Array,  # [n_tiles, W / L] int32 listed table rows
     combine_ids: jax.Array,  # [n_lanes] int32 arm index per lane
     msgs: jax.Array,  # [n_lanes, num_windows * window] ragged lane state
     *,
@@ -297,8 +387,10 @@ def ell_partials_ragged(
     The grid is ``(n_lanes / LB, n_tiles)`` with ``LB`` from
     :func:`ragged_lane_block`: one step holds one tile's index and mask
     blocks and ``LB`` lanes' windows, and loops over those lanes in-kernel.
-    A second prefetched scalar vector carries each lane's combine-arm id so
-    the selection happens in-kernel instead of at launch granularity.
+    ``n_iter`` and ``tile_rows`` come from :func:`ragged_row_table`: the
+    iteration counts arrive by scalar prefetch beside ``tile_window`` and
+    each lane's combine-arm id, and a step's row list as an SMEM block (the
+    whole table would not fit SMEM).
     """
     k = ell_idx.shape[1]
     n_lanes = msgs.shape[0]
@@ -306,21 +398,23 @@ def ell_partials_ragged(
     tab = _table(msgs, window)
     rows, lanes = window // tab.shape[-1], tab.shape[-1]
 
-    def call(tw, idx, valid):
+    def call(tw, nit, trows, idx, valid):
         n_tiles = tw.shape[0]
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(n_lanes // lb, n_tiles),
             in_specs=[
-                pl.BlockSpec((tr, k), lambda b, i, tw, cid: (i, 0)),
-                pl.BlockSpec((tr, k), lambda b, i, tw, cid: (i, 0)),
+                pl.BlockSpec((tr, k), lambda b, i, *_: (i, 0)),
+                pl.BlockSpec((tr, k), lambda b, i, *_: (i, 0)),
+                pl.BlockSpec((None, 1, rows), lambda b, i, *_: (i, 0, 0),
+                             memory_space=pltpu.SMEM),
                 # Sliding window per lane block: one window of each of the
                 # block's lanes' message tables resident per grid step.
                 pl.BlockSpec((lb, rows, lanes),
-                             lambda b, i, tw, cid: (b, tw[i], 0)),
+                             lambda b, i, tw, *_: (b, tw[i], 0)),
             ],
             out_specs=pl.BlockSpec((lb, None, 1, tr),
-                                   lambda b, i, tw, cid: (b, i, 0, 0)),
+                                   lambda b, i, *_: (b, i, 0, 0)),
         )
         out = pl.pallas_call(
             functools.partial(_ragged_kernel, combines),
@@ -329,10 +423,11 @@ def ell_partials_ragged(
                                            msgs.dtype),
             name="ell_partials_ragged",
             interpret=not kernels.pallas_compiled(),
-        )(tw, combine_ids, idx, valid, tab)
+        )(tw, nit, combine_ids, idx, valid, trows[:, None], tab)
         return out.reshape(n_lanes, n_tiles * tr)
 
-    return _over_tile_chunks(call, tile_window, tr, ell_idx, ell_valid)
+    return _over_tile_chunks(call, (tile_window, n_iter, tile_rows), tr,
+                             ell_idx, ell_valid)
 
 
 # -------------------------------------------------------------- sentinel
@@ -376,4 +471,4 @@ def ell_partials_sentinel(
         )(tw, idx, tab)
         return out.reshape(n_tiles * tr)
 
-    return _over_tile_chunks(call, tile_window, tr, ell_idx)
+    return _over_tile_chunks(call, (tile_window,), tr, ell_idx)

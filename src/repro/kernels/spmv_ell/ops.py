@@ -282,11 +282,15 @@ def _update_lanes_ragged_jit(
     the segment combine runs once per arm with the selected rows kept via
     ``jnp.where`` — each lane's value is op-for-op what
     :func:`_update_lanes_jit` computes for its group alone, so the bitwise
-    contract of the multi path is preserved (DESIGN.md §14).
+    contract of the multi path is preserved (DESIGN.md §14).  Its prologue
+    builds the kernel's per-tile row lists from the launched slots, so each
+    tile runs only the gather rounds its valid slots read.
     """
+    n_iter, tile_rows = K.ragged_row_table(ell_idx, ell_valid,
+                                           window=window, tr=tr)
     part = K.ell_partials_ragged(
-        ell_idx, ell_valid, tile_window, combine_ids, msgs2d,
-        window=window, tr=tr, combines=combines,
+        ell_idx, ell_valid, tile_window, n_iter, tile_rows, combine_ids,
+        msgs2d, window=window, tr=tr, combines=combines,
     )
     acc = jnp.zeros((msgs2d.shape[0], rows), msgs2d.dtype)
     for ci, combine in enumerate(combines):

@@ -94,13 +94,21 @@ def test_ragged_compiles_for_v5e(one_chip, lanes, n_ell, n_windows,
     from repro.kernels.spmv_ell import kernel as Kn
 
     assert lanes // Kn.ragged_lane_block(lanes, W) == lane_blocks
+    n_tiles, rows = n_ell // TR, W // 128
+    ell = (one_chip((n_ell, K), jnp.int16), one_chip((n_ell, K), jnp.bool_))
     c = Kn.ell_partials_ragged.lower(
-        one_chip((n_ell, K), jnp.int16), one_chip((n_ell, K), jnp.bool_),
-        one_chip((n_ell // TR,), jnp.int32), one_chip((lanes,), jnp.int32),
+        *ell, one_chip((n_tiles,), jnp.int32),
+        one_chip((n_tiles,), jnp.int32),  # row-loop iterations
+        one_chip((n_tiles, rows), jnp.int32),  # row lists, one SMEM block
+        one_chip((lanes,), jnp.int32),
         one_chip((lanes, n_windows * W), jnp.float32),
         window=W, tr=TR, combines=COMBINES,
     ).compile()
     _check(c)
+    # the prologue that builds the row lists on the device
+    table = jax.jit(Kn.ragged_row_table, static_argnames=("window", "tr"))
+    c = table.lower(*ell, window=W, tr=TR).compile()
+    assert [o.shape for o in c.out_info] == [(n_tiles,), (n_tiles, rows)]
 
 
 def test_mesh_ragged_step_compiles_for_v5e_2x2(topo, tpu_kernels):

@@ -10,7 +10,9 @@ masks off, and lane-selective scheduling that sets them):
 - the dispatch counters add up: real edges over an iteration that planned
   every shard are the graph's edges, slots cover edges, live lanes fit the
   launched lanes, and the launched lanes cover ``ExecStats.ragged_lanes``;
-- a Pallas dispatch counts its kernel's grid steps (lane blocks × tiles);
+- a Pallas dispatch counts its kernel's grid steps (lane blocks × tiles)
+  and the gather rounds each lane runs, as the host helper counts them
+  from the launched slots;
 - tracing leaves every result bitwise as it was.
 
 Besides: a backend compile is recorded as a ``jax.compile`` span under the
@@ -105,6 +107,18 @@ def traced_sweep(request, tmp_path_factory):
         ragged_lanes[0] += stats.ragged_lanes - before
 
     sweep.executor.run_groups = counted
+    # the rounds the host helper counts on each launch's device arrays
+    ragged_fn, launched_rounds = sweep.executor._ragged_fn, []
+
+    def launch(batch, n_ell_pad, staged, lane_ctx):
+        from repro.kernels.spmv_ell import kernel as Kn
+
+        launched_rounds.append(Kn.ragged_rounds(Kn.ragged_rows_read(
+            np.asarray(staged[0]), np.asarray(staged[1]), window=WINDOW,
+            tr=TR)))
+        return ragged_fn(batch, n_ell_pad, staged, lane_ctx)
+
+    sweep.executor._ragged_fn = launch
     tr = Tracer()
     with trace.tracing(tr):
         traced = {r.token: r for r in sweep.run(_seeds(not lane_selective))}
@@ -112,7 +126,7 @@ def traced_sweep(request, tmp_path_factory):
     return {"spans": _spans(tr, threading.get_ident()), "untraced": untraced,
             "traced": traced, "ragged_lanes": ragged_lanes[0],
             "masked": lane_selective, "edges": g.num_edges,
-            "backend": backend,
+            "backend": backend, "launched_rounds": launched_rounds,
             "num_shards": eng.meta.num_shards, "tracer": tr}
 
 
@@ -187,6 +201,23 @@ def test_pallas_dispatch_grid_steps_e2e(traced_sweep):
                                                           WINDOW)
         n_ell_pad = a["slots"] // K
         assert a["grid_steps"] == lane_blocks * n_ell_pad // TR > 0
+
+
+def test_pallas_dispatch_rounds_e2e(traced_sweep):
+    """A Pallas ragged dispatch counts the gather rounds each lane runs,
+    equal to the host helper's count on the arrays it launched (padding
+    tiles run none), against every table row of every tile; a jnp dispatch
+    counts none."""
+    dispatches = [d[3] for d in _named(traced_sweep["spans"], "exec.dispatch")]
+    assert len(dispatches) == len(traced_sweep["launched_rounds"]) > 0
+    if traced_sweep["backend"] != "pallas":
+        assert not any("rounds" in a or "rounds_full" in a for a in dispatches)
+        return
+    assert [a["rounds"] for a in dispatches] == \
+        traced_sweep["launched_rounds"]
+    for a in dispatches:
+        assert a["rounds_full"] == a["slots"] // (K * TR) * (WINDOW // 128)
+        assert 0 < a["rounds"] <= a["rounds_full"]
 
 
 def test_traced_sweep_results_bitwise_untraced_e2e(traced_sweep):

@@ -229,7 +229,9 @@ def test_spmv_ell_tile_chunks_match_one_launch(monkeypatch):
     args = (jnp.asarray(e.ell_idx), jnp.asarray(e.ell_mask),
             jnp.asarray(e.tile_window), jnp.asarray(msgs))
     lane_args = (jnp.asarray(e.ell_idx), jnp.asarray(e.ell_mask),
-                 jnp.asarray(e.tile_window), jnp.asarray([0, 1], jnp.int32),
+                 jnp.asarray(e.tile_window),
+                 *Kn.ragged_row_table(args[0], args[1], window=256, tr=8),
+                 jnp.asarray([0, 1], jnp.int32),
                  jnp.asarray(np.stack([msgs, msgs[::-1].copy()])))
 
     def run():

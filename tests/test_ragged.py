@@ -12,7 +12,11 @@ The ragged contract, tested four ways:
    (including duplicated monoids sharing one arm and inf-heavy min
    inputs), and the mesh variant equals the mesh multi path at D ∈
    {1, 2, 8} (numpy emulation inline; jnp/pallas in a subprocess under
-   ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
+   ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  The kernel's
+   per-tile row lists change no partial: hand-built tiles (no valid slot,
+   one row, every row, several slots on a row, invalid slots on listed
+   rows) equal the masked kernel and the full schedule, and the device
+   prologue's lists equal ``np.unique`` per tile.
 3. **Bitwise sweeps** — a ``FusedSweep(ragged=True)`` reproduces the
    ``ragged=False`` multi-path results exactly through masked groups
    (lane-selective scheduling), mid-sweep retirement and backfill.
@@ -215,7 +219,8 @@ def test_ragged_lane_blocks_bitwise_vs_masked_e2e(monkeypatch, n_lanes,
     ell = (jnp.asarray(e.ell_idx), jnp.asarray(e.ell_mask),
            jnp.asarray(e.tile_window))
     out = np.asarray(Kn.ell_partials_ragged(
-        *ell, jnp.asarray(cids), jnp.asarray(msgs), window=window, tr=8,
+        *ell, *Kn.ragged_row_table(*ell[:2], window=window, tr=8),
+        jnp.asarray(cids), jnp.asarray(msgs), window=window, tr=8,
         combines=combines))
     if forced:
         jax.clear_caches()
@@ -228,6 +233,137 @@ def test_ragged_lane_blocks_bitwise_vs_masked_e2e(monkeypatch, n_lanes,
             *ell, jnp.asarray(msgs[lane]), window=window, tr=8,
             combine=combines[cid]))
         assert np.array_equal(_norm(out[lane]), _norm(ref)), lane
+
+
+# ------------------------------------------------------ row lists
+# Hand-built (TR, K) tiles over one window of W = 16384: 128 table rows of
+# L = 128, so a tile of 128 slots can read every row.
+RL_W, RL_TR, RL_K = 16384, 8, 16
+
+
+def _tile(rng, valid_rows, invalid_rows=()):
+    """One tile: a valid slot on each of ``valid_rows`` (repeats give
+    several slots on one row), an invalid slot on each of
+    ``invalid_rows``, every other slot invalid with a random index."""
+    idx = rng.integers(0, RL_W, RL_TR * RL_K)
+    valid = np.zeros(RL_TR * RL_K, bool)
+    pos = rng.permutation(RL_TR * RL_K)
+    rows = list(valid_rows) + list(invalid_rows)
+    assert len(rows) <= pos.size
+    for p, row in zip(pos, rows):
+        idx[p] = row * 128 + rng.integers(0, 128)
+    valid[pos[:len(valid_rows)]] = True
+    return idx.reshape(RL_TR, RL_K), valid.reshape(RL_TR, RL_K)
+
+
+ROW_LIST_TILES = {
+    "no-valid-slot": lambda rng: _tile(rng, []),
+    "one-row": lambda rng: _tile(rng, [77]),
+    "all-128-rows": lambda rng: _tile(rng, rng.permutation(128)),
+    "count-not-multiple": lambda rng: _tile(
+        rng, rng.choice(128, 19, replace=False)),
+    "several-slots-one-row": lambda rng: _tile(rng, [5] * 11 + [6, 90]),
+    "invalid-on-listed-row": lambda rng: _tile(
+        rng, [3, 40, 41], invalid_rows=[3, 40, 40, 41, 127]),
+}
+
+
+def _row_list_launch(case, seed):
+    """The case's tile, between a tile with no valid slot and a tile of
+    random rows, as device arrays."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    tiles = [ROW_LIST_TILES["no-valid-slot"](rng), ROW_LIST_TILES[case](rng),
+             _tile(rng, rng.choice(128, 40))]
+    idx = np.concatenate([t[0] for t in tiles]).astype(np.int16)
+    valid = np.concatenate([t[1] for t in tiles])
+    return jnp.asarray(idx), jnp.asarray(valid)
+
+
+def _unique_rows(idx, valid):
+    """Per tile of 8 ELL rows, the table rows its valid slots read:
+    ``np.unique``."""
+    hi = np.asarray(idx).reshape(-1, 8 * idx.shape[1]) >> 7
+    v = np.asarray(valid).reshape(hi.shape)
+    return [np.unique(h[m]) for h, m in zip(hi, v)]
+
+
+@pytest.mark.parametrize("case", sorted(ROW_LIST_TILES))
+def test_ragged_row_lists_bitwise_e2e(case):
+    """Each lane of the row-list kernel equals a solo masked launch with its
+    combine and the full schedule (every row listed for every tile), on
+    the min and the sum arms; a lane whose id matches no arm is zeros."""
+    import jax.numpy as jnp
+
+    from repro.kernels.spmv_ell import kernel as Kn
+
+    idx, valid = _row_list_launch(case, 150)
+    n_tiles = idx.shape[0] // RL_TR
+    tw = jnp.zeros((n_tiles,), jnp.int32)
+    combines = ("min", "sum")
+    cids = np.array([0, 1, 2, 0, 1], np.int32)
+    rng = np.random.default_rng(151)
+    msgs = rng.standard_normal((cids.size, RL_W)).astype(np.float32)
+    msgs[0, rng.random(RL_W) > 0.5] = np.inf
+    n_iter, tile_rows = Kn.ragged_row_table(idx, valid, window=RL_W,
+                                            tr=RL_TR)
+    step = Kn._rows_per_iter(128)
+    full = (jnp.full((n_tiles,), 128 // step, jnp.int32),
+            jnp.tile(jnp.arange(128, dtype=jnp.int32), (n_tiles, 1)))
+
+    def launch(table):
+        return np.asarray(Kn.ell_partials_ragged(
+            idx, valid, tw, *table, jnp.asarray(cids), jnp.asarray(msgs),
+            window=RL_W, tr=RL_TR, combines=combines))
+
+    out, out_full = launch((n_iter, tile_rows)), launch(full)
+    assert np.array_equal(_norm(out), _norm(out_full))
+    for lane, cid in enumerate(cids):
+        if cid == len(combines):
+            assert np.all(out[lane] == 0.0), lane
+            continue
+        ref = np.asarray(Kn.ell_partials_masked(
+            idx, valid, tw, jnp.asarray(msgs[lane]), window=RL_W, tr=RL_TR,
+            combine=combines[cid]))
+        assert np.array_equal(_norm(out[lane]), _norm(ref)), lane
+
+
+@pytest.mark.parametrize("case", sorted(ROW_LIST_TILES) + [
+    "rmat-w128", "rmat-w256", "rmat-w1024"])
+def test_ragged_row_table_matches_host_helper_e2e(case):
+    """The device prologue's row lists equal ``np.unique`` per tile, padded
+    with the last listed row to whole loop iterations, and its iteration
+    counts the host helper's, whose round count the dispatch counter
+    records."""
+    import jax.numpy as jnp
+
+    from repro.kernels.spmv_ell import kernel as Kn
+
+    if case.startswith("rmat-w"):
+        window = int(case[6:])
+        g = rmat_graph(600, 7000, seed=152)
+        _, shards = preprocess(g, num_shards=2)
+        e = csr_to_ell(shards[1], g.num_vertices, window=window, k=16, tr=8)
+        idx, valid = jnp.asarray(e.ell_idx), jnp.asarray(e.ell_mask)
+    else:
+        window = RL_W
+        idx, valid = _row_list_launch(case, 153)
+    rows = window // 128
+    step = Kn._rows_per_iter(rows)
+    n_iter, tile_rows = (np.asarray(x) for x in Kn.ragged_row_table(
+        idx, valid, window=window, tr=8))
+    read = Kn.ragged_rows_read(np.asarray(idx), np.asarray(valid),
+                               window=window, tr=8)
+    uniq = _unique_rows(idx, valid)
+    assert tile_rows.shape == (len(uniq), rows)
+    for t, u in enumerate(uniq):
+        assert np.array_equal(np.flatnonzero(read[t]), u), t
+        assert n_iter[t] == -(-u.size // step), t
+        pad = u[-1] if u.size else 0
+        assert np.array_equal(tile_rows[t, :u.size], u), t
+        assert np.all(tile_rows[t, u.size:] == pad), t
+    assert Kn.ragged_rounds(read) == int(n_iter.sum()) * step
 
 
 # -------------------------------------------------------- sweep bitwise
