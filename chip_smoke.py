@@ -7,7 +7,7 @@
 The graph is a Graph500 Kronecker graph (R-MAT with A, B, C = 0.57, 0.19,
 0.19, edge factor 16) made from ``--seed`` and written to a shard store
 through ``VSWEngine.from_graph``.  Every engine and service opens that one
-store with the default widths (W=16384, K=128, ragged serving on).
+store with the default widths (W=16384, K=128).
 
 - analytics: PageRank for a fixed number of iterations and BFS from a
   seeded source to convergence on ``VSWEngine(backend="pallas")``.

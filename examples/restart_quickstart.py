@@ -20,8 +20,7 @@ filters.  A warm boot restores that state from a checkpoint instead:
    warm, and answers are still correct.
 
 An `emulate_bw` throttle makes the boot-time difference visible on a
-small example; `fig_restart` (benchmarks/bench_graphmp.py) measures the
-same story in CI.
+small example.
 
 Run:  PYTHONPATH=src python examples/restart_quickstart.py
 """

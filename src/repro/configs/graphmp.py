@@ -1,8 +1,8 @@
 """The paper's own workload: graph sizes for the GraphMP engine.
 
 ``EU2015`` is the paper's largest dataset (1.07B vertices, 91.8B edges);
-used as ShapeDtypeStructs by the distributed dry-run.  ``TESTBED`` sizes
-run for real in benchmarks.
+used as ShapeDtypeStructs by the distributed dry-run.  The others are the
+paper's smaller datasets.
 """
 
 import dataclasses

@@ -11,9 +11,10 @@ computation models, as closed-form functions of:
     theta  cache miss ratio (VSW read term), 0 <= theta <= 1
     d_avg  average degree (VSP's v-shard duplication factor delta)
 
-``benchmarks/bench_io_model.py`` prints this table for the paper's datasets
-and cross-checks the VSW/PSW/ESG/DSW rows against *measured* bytes from the
-real engines on synthetic graphs.
+:func:`io_table` gives this table for a set of parameters;
+``tests/test_baselines.py`` checks its orderings and cross-checks the
+VSW/PSW/ESG/DSW rows against *measured* bytes from the real engines on
+synthetic graphs.
 """
 
 from __future__ import annotations

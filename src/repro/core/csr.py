@@ -231,13 +231,13 @@ def concat_ells(ells: Sequence[EllShard]) -> EllBatch:
 def ragged_lane_pad(lane_counts: Sequence[int]) -> int:
     """Padded lane count for ONE ragged launch covering all fusion groups.
 
-    The multi-launch path pads every group to its own power of two, so its
-    total waste is ``sum(next_pow2(k_g)) - sum(k_g)``.  A single ragged
-    launch only needs ONE padded lane axis; padding the concatenated count
-    to ``next_pow2(K_total)`` keeps the jit shape-bucket behaviour but can
-    exceed the per-group waste (e.g. counts ``1,1,1`` -> 4 vs 3), so the
-    target is capped at the per-group pow2 total — ragged waste is then
-    provably never worse than the G-launch waste.
+    Padding every group to its own power of two would waste
+    ``sum(next_pow2(k_g)) - sum(k_g)`` lanes.  A single ragged launch only
+    needs ONE padded lane axis; padding the concatenated count to
+    ``next_pow2(K_total)`` keeps the jit shape-bucket behaviour but can
+    exceed that per-group waste (e.g. counts ``1,1,1`` -> 4 vs 3), so the
+    target is capped at the per-group pow2 total — the ragged waste is then
+    provably never worse.
     """
     k_total = int(sum(int(k) for k in lane_counts))
     per_group = int(sum(next_pow2(max(int(k), 1)) for k in lane_counts))

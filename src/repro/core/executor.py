@@ -4,7 +4,7 @@ Third layer of the engine stack.  An executor consumes the pipeline's
 stream of loaded shards and yields per-shard accumulators; it owns the
 backend dispatch the engine used to do inline.
 
-Two strategies:
+Single-query strategies (:func:`make_executor`):
 
 - :class:`PerShardExecutor` — one backend call per shard (the paper's
   worker model; also the only choice for the numpy oracle, whose
@@ -16,6 +16,12 @@ Two strategies:
   batch is a pure concatenation, so every tile computes identical partials
   and the globalized segment combine preserves per-segment contribution
   order.
+
+Lane (concurrent-query) executors (:func:`make_lane_executor`): the jnp and
+pallas backends dispatch lanes one way only, the ragged launch of
+:class:`RaggedLaneExecutor` (DESIGN.md §14); the numpy oracle runs
+:class:`PerShardExecutor` with ``lanes=True``; a mesh engine runs
+:class:`MeshLaneExecutor`.
 
 Shard-update backends (moved here from ``vsw.py``); signature
 ``(csr, ell, msgs, combine) -> acc [rows] float32``:
@@ -59,15 +65,13 @@ __all__ = [
     "ExecStats",
     "PerShardExecutor",
     "BatchedEllExecutor",
+    "RaggedLaneExecutor",
     "make_executor",
     "make_lane_executor",
     "update_shard_numpy",
     "update_shard_jnp",
     "update_shards_jnp_batched",
     "update_shard_numpy_lanes",
-    "update_shard_jnp_lanes",
-    "update_shards_jnp_lanes_batched",
-    "update_shards_jnp_lanes_multi",
     "GroupDispatch",
 ]
 
@@ -101,9 +105,9 @@ def update_shard_numpy(
 def _ell_fn_impl(tr: int, rows: int, window: int, combine: str):
     """The pure (un-jitted) windowed-ELL update for one padded shape bucket.
 
-    Shared by the single-query path (jitted directly) and the serving
-    layer's lane path (jitted under ``vmap`` over the message axis) so both
-    trace the exact same per-lane computation.
+    Shared by the single-query path (jitted directly) and the ragged lane
+    launch (one arm per combine, vmapped over the lane axis) so both trace
+    the exact same per-lane computation.
     """
     import jax
     import jax.numpy as jnp
@@ -139,41 +143,22 @@ def _jnp_ell_fn(n_ell: int, k: int, tr: int, rows: int, window: int, combine: st
     return jax.jit(_ell_fn_impl(tr, rows, window, combine))
 
 
-@functools.lru_cache(maxsize=64)
-def _jnp_ell_lanes_fn(
-    n_ell: int, k: int, tr: int, rows: int, window: int, combine: str
-):
-    """Lane-batched variant: one jit dispatch updates ``[lanes, ...]``
-    message rows against shared edge structure (lane count is a traced
-    shape; the serving batcher pads it to pow2 to bound retraces)."""
-    import jax
-
-    return jax.jit(
-        jax.vmap(_ell_fn_impl(tr, rows, window, combine),
-                 in_axes=(None, None, None, None, 0))
-    )
-
-
 def _padded_shard_inputs(ell: EllShard, msgs: np.ndarray):
     """Shape-bucket one shard's ELL arrays and pad messages to full windows
-    (so the gather never reads OOB).  ``msgs`` may be 1-D (single query) or
-    2-D ``[lanes, |V|]`` — only the trailing (vertex) axis is padded.
-    Shared by the single-query and lane paths so the padding discipline
-    can't drift between them."""
+    (so the gather never reads OOB)."""
     n_ell_pad = bucket_rows(ell.n_ell, ell.tr)
     idx, mask, seg, tw = pad_ell_arrays(
         ell.ell_idx, ell.ell_mask, ell.seg, ell.tile_window,
         ell.n_ell, ell.tr, n_ell_pad,
     )
     n_pad_v = ell.num_windows * ell.window
-    pad = [(0, 0)] * (msgs.ndim - 1) + [(0, n_pad_v - msgs.shape[-1])]
-    return n_ell_pad, idx, mask, seg, tw, np.pad(msgs, pad)
+    return n_ell_pad, idx, mask, seg, tw, np.pad(msgs, (0, n_pad_v - len(msgs)))
 
 
 def _staged_batch(ells: List[EllShard]):
-    """Concatenate + shape-bucket a shard batch (the shard-side staging
-    every batched lane path shares — single-group and multi-group dispatch
-    MUST pad identically or fusion stops being bitwise-invisible)."""
+    """Concatenate + shape-bucket a shard batch (the shard-side staging of
+    the batched single-query path and the ragged lane launch — both MUST
+    pad identically or fusion stops being bitwise-invisible)."""
     batch = concat_ells(ells)
     n_ell_pad = bucket_rows(batch.n_ell, batch.tr)
     idx, mask, seg, tw = pad_ell_arrays(
@@ -181,14 +166,6 @@ def _staged_batch(ells: List[EllShard]):
         batch.n_ell, batch.tr, n_ell_pad,
     )
     return batch, n_ell_pad, idx, mask, seg, tw
-
-
-def _padded_batch_inputs(ells: List[EllShard], msgs: np.ndarray):
-    """Batch-level counterpart of :func:`_padded_shard_inputs`."""
-    batch, n_ell_pad, idx, mask, seg, tw = _staged_batch(ells)
-    n_pad_v = batch.num_windows * batch.window
-    pad = [(0, 0)] * (msgs.ndim - 1) + [(0, n_pad_v - msgs.shape[-1])]
-    return batch, n_ell_pad, idx, mask, seg, tw, np.pad(msgs, pad)
 
 
 def update_shard_jnp(
@@ -221,9 +198,8 @@ def update_shards_jnp_batched(
 
     if not ells:
         return []
-    batch, n_ell_pad, idx, mask, seg, tw, msgs_p = _padded_batch_inputs(
-        ells, msgs
-    )
+    batch, n_ell_pad, idx, mask, seg, tw = _staged_batch(ells)
+    msgs_p = np.pad(msgs, (0, batch.num_windows * batch.window - len(msgs)))
     rows_pad = next_pow2(batch.rows_total)
     fn = _jnp_ell_fn(n_ell_pad, batch.k, batch.tr, rows_pad, batch.window,
                      combine)
@@ -265,75 +241,6 @@ def update_shard_numpy_lanes(
     )
 
 
-def update_shard_jnp_lanes(
-    csr: ShardCSR, ell: EllShard, msgs: np.ndarray, combine: str
-) -> np.ndarray:
-    """Windowed-ELL gather/combine for all lanes under ONE jit dispatch."""
-    import jax.numpy as jnp
-
-    n_ell_pad, idx, mask, seg, tw, msgs_p = _padded_shard_inputs(ell, msgs)
-    fn = _jnp_ell_lanes_fn(n_ell_pad, ell.k, ell.tr, ell.rows, ell.window,
-                           combine)
-    acc = fn(jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(seg),
-             jnp.asarray(tw), jnp.asarray(msgs_p))
-    return np.asarray(acc)
-
-
-def update_shards_jnp_lanes_batched(
-    ells: List[EllShard], msgs: np.ndarray, combine: str
-) -> List[np.ndarray]:
-    """One jit dispatch for N concatenated shards x K lanes (jnp backend) —
-    same shape-bucketing discipline as :func:`update_shards_jnp_batched`."""
-    import jax.numpy as jnp
-
-    if not ells:
-        return []
-    batch, n_ell_pad, idx, mask, seg, tw, msgs_p = _padded_batch_inputs(
-        ells, msgs
-    )
-    rows_pad = next_pow2(batch.rows_total)
-    fn = _jnp_ell_lanes_fn(n_ell_pad, batch.k, batch.tr, rows_pad,
-                           batch.window, combine)
-    acc = fn(jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(seg),
-             jnp.asarray(tw), jnp.asarray(msgs_p))
-    return batch.split(np.asarray(acc))
-
-
-def update_shards_jnp_lanes_multi(
-    ells: List[EllShard],
-    msgs_by_group: Sequence[np.ndarray],
-    combines: Sequence[str],
-) -> List[List[np.ndarray]]:
-    """Multi-GROUP lane dispatch (fused sweeps, DESIGN.md §9): N shards are
-    concatenated / shape-bucketed / staged ONCE, then dispatched once per
-    program group against that group's own ``[K_g, |V|]`` lane matrix and
-    combine monoid — G dispatches share one decode+concat.  Each group's
-    dispatch is the exact computation
-    :func:`update_shards_jnp_lanes_batched` would run for it alone (same
-    padded arrays, same jit'd function), so fusion stays bitwise-invisible
-    per lane.  Returns one per-shard accumulator list per group.
-    """
-    import jax.numpy as jnp
-
-    if not ells:
-        return [[] for _ in msgs_by_group]
-    batch, n_ell_pad, idx, mask, seg, tw = _staged_batch(ells)
-    idx_j, mask_j, seg_j, tw_j = (
-        jnp.asarray(idx), jnp.asarray(mask), jnp.asarray(seg), jnp.asarray(tw)
-    )
-    rows_pad = next_pow2(batch.rows_total)
-    n_pad_v = batch.num_windows * batch.window
-    out: List[List[np.ndarray]] = []
-    for msgs, combine in zip(msgs_by_group, combines):
-        msgs_p = np.zeros((msgs.shape[0], n_pad_v), msgs.dtype)
-        msgs_p[:, : msgs.shape[1]] = msgs
-        fn = _jnp_ell_lanes_fn(n_ell_pad, batch.k, batch.tr, rows_pad,
-                               batch.window, combine)
-        acc = fn(idx_j, mask_j, seg_j, tw_j, jnp.asarray(msgs_p))
-        out.append(batch.split(np.asarray(acc)))
-    return out
-
-
 @functools.lru_cache(maxsize=64)
 def _jnp_ell_lanes_ragged_fn(
     n_ell: int, k: int, tr: int, rows: int, window: int, combines: tuple
@@ -341,9 +248,9 @@ def _jnp_ell_lanes_ragged_fn(
     """RaggedFuse jnp variant: ONE jit dispatch updates the concatenated
     lane state of ALL fusion groups, selecting each lane's combine arm via
     its ``combine_ids`` entry.  The per-arm bodies are the exact
-    :func:`_ell_fn_impl` closures the per-group multi path vmaps, and
+    :func:`_ell_fn_impl` closures the single-query path jits, and
     ``jnp.where`` keeps the selected arm's value bit-for-bit, so each
-    lane's row is bitwise :func:`update_shards_jnp_lanes_multi`'s."""
+    lane's row is bitwise a solo :func:`update_shard_jnp` run."""
     import jax
     import jax.numpy as jnp
 
@@ -406,37 +313,6 @@ def _ragged_collect(batch, acc, group_slices) -> List[List[np.ndarray]]:
         return [batch.split(acc[sl]) for sl in group_slices]
 
 
-def _update_shard_pallas_lanes(
-    csr: ShardCSR, ell: EllShard, msgs: np.ndarray, combine: str
-) -> np.ndarray:
-    from repro.kernels.spmv_ell import ops as spmv_ops
-
-    return np.asarray(spmv_ops.ell_update_lanes(ell, msgs, combine))
-
-
-def _update_shards_pallas_lanes_batched(
-    ells: List[EllShard], msgs: np.ndarray, combine: str
-) -> List[np.ndarray]:
-    from repro.kernels.spmv_ell import ops as spmv_ops
-
-    return [np.asarray(a)
-            for a in spmv_ops.ell_update_lanes_batched(ells, msgs, combine)]
-
-
-def _update_shards_pallas_lanes_multi(
-    ells: List[EllShard],
-    msgs_by_group: Sequence[np.ndarray],
-    combines: Sequence[str],
-) -> List[List[np.ndarray]]:
-    from repro.kernels.spmv_ell import ops as spmv_ops
-
-    return [
-        [np.asarray(a) for a in accs]
-        for accs in spmv_ops.ell_update_lanes_multi(ells, msgs_by_group,
-                                                    combines)
-    ]
-
-
 BACKENDS: Dict[str, Callable] = {
     "numpy": update_shard_numpy,
     "jnp": update_shard_jnp,
@@ -448,20 +324,10 @@ _BATCHED_BACKENDS: Dict[str, Callable] = {
     "pallas": _update_shards_pallas_batched,
 }
 
+#: Per-shard lane backends: the numpy oracle only (the ELL backends
+#: dispatch lanes through :class:`RaggedLaneExecutor`).
 LANE_BACKENDS: Dict[str, Callable] = {
     "numpy": update_shard_numpy_lanes,
-    "jnp": update_shard_jnp_lanes,
-    "pallas": _update_shard_pallas_lanes,
-}
-
-_BATCHED_LANE_BACKENDS: Dict[str, Callable] = {
-    "jnp": update_shards_jnp_lanes_batched,
-    "pallas": _update_shards_pallas_lanes_batched,
-}
-
-_MULTI_LANE_BACKENDS: Dict[str, Callable] = {
-    "jnp": update_shards_jnp_lanes_multi,
-    "pallas": _update_shards_pallas_lanes_multi,
 }
 
 _RAGGED_LANE_BACKENDS: Dict[str, Callable] = {
@@ -541,8 +407,8 @@ class ExecStats:
     shards_executed: int = 0
     exec_s: float = 0.0
     #: shard batches flushed this iteration (a ragged flush is ONE dispatch
-    #: per batch; the multi path pays G — conservation:
-    #: ragged_dispatches <= batches <= dispatches, DESIGN.md §14).
+    #: per batch — conservation: ragged_dispatches <= batches <=
+    #: dispatches, DESIGN.md §14).
     batches: int = 0
     ragged_dispatches: int = 0
     #: live (un-padded) lanes covered by ragged launches, summed per flush;
@@ -572,8 +438,8 @@ class PerShardExecutor:
     """One backend call per loaded shard (paper worker model).
 
     With ``lanes=True`` the call consumes ``[lanes, |V|]`` messages and
-    yields ``[lanes, rows]`` accumulators — the serving layer's per-shard
-    amortization (one load, K lanes).
+    yields ``[lanes, rows]`` accumulators (one load, K lanes): the numpy
+    lane oracle, the only per-shard lane backend (:data:`LANE_BACKENDS`).
     """
 
     def __init__(self, backend: str, *, lanes: bool = False):
@@ -643,16 +509,10 @@ class PerShardExecutor:
 
 
 class BatchedEllExecutor:
-    """Batch consecutive planned ELL shards into one kernel dispatch.
+    """Batch consecutive planned ELL shards into one kernel dispatch."""
 
-    With ``lanes=True`` each dispatch covers N shards x K query lanes —
-    the serving hot loop's maximal amortization point.
-    """
-
-    def __init__(self, backend: str, batch_shards: int = 4, *,
-                 lanes: bool = False, ragged: bool = True):
-        table = _BATCHED_LANE_BACKENDS if lanes else _BATCHED_BACKENDS
-        if backend not in table:
+    def __init__(self, backend: str, batch_shards: int = 4):
+        if backend not in _BATCHED_BACKENDS:
             raise ValueError(
                 f"batched execution needs an ELL backend, got {backend!r}"
             )
@@ -660,15 +520,7 @@ class BatchedEllExecutor:
             raise ValueError("batch_shards must be >= 1")
         self.backend_name = backend
         self.batch_shards = batch_shards
-        self.lanes = lanes
-        #: RaggedFuse (DESIGN.md §14): run_groups concatenates every live
-        #: group along the lane axis and launches ONE ragged kernel per
-        #: shard batch instead of G, double-buffering collection against
-        #: the next batch's host decode.
-        self.ragged = bool(ragged) and lanes and backend in _RAGGED_LANE_BACKENDS
-        self._fn = table[backend]
-        self._multi_fn = _MULTI_LANE_BACKENDS[backend] if lanes else None
-        self._ragged_fn = _RAGGED_LANE_BACKENDS.get(backend) if lanes else None
+        self._fn = _BATCHED_BACKENDS[backend]
 
     def run(
         self,
@@ -702,6 +554,41 @@ class BatchedEllExecutor:
                 batch_size=len(buf),
             )
 
+
+class RaggedLaneExecutor:
+    """Lane dispatch of the ELL backends (jnp, pallas): RaggedFuse
+    (DESIGN.md §14).
+
+    Up to ``batch_shards`` consecutive shards are concatenated ONCE, every
+    live program group rides the lane axis of ONE ragged launch per batch
+    (each lane selecting its combine arm), and the collect of batch ``i``
+    waits until batch ``i+1`` is in flight (double buffering).  Each
+    lane's row is bitwise a solo run of that lane's program.
+    """
+
+    def __init__(self, backend: str, batch_shards: int = 1):
+        if backend not in _RAGGED_LANE_BACKENDS:
+            raise ValueError(
+                f"lane dispatch needs an ELL backend, got {backend!r}"
+            )
+        if batch_shards < 1:
+            raise ValueError("batch_shards must be >= 1")
+        self.backend_name = backend
+        self.batch_shards = batch_shards
+        self._ragged_fn = _RAGGED_LANE_BACKENDS[backend]
+
+    def run(
+        self,
+        loaded: Iterable[LoadedShard],
+        msgs: np.ndarray,
+        combine: str,
+        stats: Optional[ExecStats] = None,
+    ) -> Iterator[ExecResult]:
+        """One program group: ``msgs`` is ``[lanes, |V|]`` and each result
+        carries ``[lanes, rows]``."""
+        for _, res in self.run_groups(loaded, [(msgs, combine)], stats):
+            yield res
+
     def run_groups(
         self,
         loaded: Iterable[LoadedShard],
@@ -711,55 +598,6 @@ class BatchedEllExecutor:
         masked: bool = False,
         lanes_live: Optional[int] = None,
     ) -> Iterator[Tuple[int, ExecResult]]:
-        """Multi-group batched dispatch: up to ``batch_shards`` consecutive
-        shards are concatenated ONCE (shared decode + concat + pad staging)
-        and dispatched once per live program group — the fused serving hot
-        loop's cost shape: 1 load, 1 concat, G kernel launches per batch.
-        """
-        if not self.lanes:
-            raise RuntimeError("run_groups needs a lane executor")
-        if self.ragged:
-            yield from self._run_groups_ragged(loaded, groups, stats,
-                                               masked, lanes_live)
-            return
-        buf: List[LoadedShard] = []
-        for ls in loaded:
-            buf.append(ls)
-            if len(buf) >= self.batch_shards:
-                yield from self._flush_groups(buf, groups, stats)
-                buf = []
-        if buf:
-            yield from self._flush_groups(buf, groups, stats)
-
-    def _flush_groups(self, buf, groups, stats):
-        live = [(gi, ga) for gi, ga in enumerate(groups) if ga is not None]
-        if not live:
-            return
-        t0 = time.perf_counter()
-        with trace.span(
-            "exec.dispatch",
-            shards=len(buf),
-            groups=len(live),
-            backend=self.backend_name,
-        ):
-            accs_by_group = self._multi_fn(
-                [ls.ell for ls in buf],
-                [ga[0] for _, ga in live],
-                [ga[1] for _, ga in live],
-            )
-        if stats is not None:
-            stats.dispatches += len(live)
-            stats.batches += 1
-            stats.shards_executed += len(buf) * len(live)
-            stats.exec_s += time.perf_counter() - t0
-        for (gi, _), accs in zip(live, accs_by_group):
-            for ls, acc in zip(buf, accs):
-                yield gi, ExecResult(
-                    ls.shard_id, ls.ell.v0, ls.ell.v1, np.asarray(acc),
-                    batch_size=len(buf),
-                )
-
-    def _run_groups_ragged(self, loaded, groups, stats, masked, lanes_live):
         """RaggedFuse hot loop: 1 load, 1 concat, ONE kernel launch per
         batch covering every live group, with the collect of batch ``i``
         deferred until batch ``i+1`` has been dispatched — the launch stays
@@ -769,7 +607,7 @@ class BatchedEllExecutor:
         """
         live = [(gi, ga) for gi, ga in enumerate(groups) if ga is not None]
         if not live:
-            for _ in loaded:  # consume the stream exactly like the G-path
+            for _ in loaded:  # the shard stream is still consumed once
                 pass
             return
         lane_ctx = None  # staged on first flush, reused across batches
@@ -858,15 +696,17 @@ class BatchedEllExecutor:
 
 class MeshLaneExecutor:
     """SPMD executor: route each loaded shard to its owning device's batch
-    and dispatch every device's batch in ONE ``shard_map`` launch per live
-    program group — "1 host read, G x D slices" (DESIGN.md §10).
+    and dispatch every device's batch, for every live program group, in
+    ONE ``shard_map`` step — "1 host read, 1 SPMD step, D slices"
+    (DESIGN.md §10, §14).
 
     Shards buffer per device (by :class:`MeshPartition` ownership) up to
     ``batch_shards`` each; a flush dispatches ALL devices together, so the
-    dispatch count is per SPMD program, not per device — each group's
-    launch covers every device's slice.  Devices whose buffer is empty this
-    round (inactive destination intervals pruned by the scheduler) ride
-    along as identity-padded zero blocks inside the same program.
+    dispatch count is per SPMD program, not per device.  Devices whose
+    buffer is empty this round (inactive destination intervals pruned by
+    the scheduler) ride along as identity-padded zero blocks inside the
+    same program.  Collection is double-buffered against the next round's
+    host decode.
 
     ``backend="numpy"`` is the mesh EMULATION path: identical routing,
     flush cadence and accounting, but per-shard numpy-oracle calls and no
@@ -875,11 +715,10 @@ class MeshLaneExecutor:
     """
 
     def __init__(self, backend: str, partition, mesh=None, *,
-                 batch_shards: int = 1, lanes: bool = False,
-                 ragged: bool = True):
-        if backend not in LANE_BACKENDS:
+                 batch_shards: int = 1):
+        if backend not in BACKENDS:
             raise ValueError(
-                f"unknown backend {backend}; have {sorted(LANE_BACKENDS)}"
+                f"unknown backend {backend}; have {sorted(BACKENDS)}"
             )
         if backend != "numpy" and mesh is None:
             raise ValueError("jnp/pallas mesh execution needs a jax Mesh")
@@ -889,13 +728,6 @@ class MeshLaneExecutor:
         self.partition = partition
         self.mesh = mesh
         self.batch_shards = batch_shards
-        self.lanes = lanes
-        #: RaggedFuse under the mesh: one shard_map step per flush covers
-        #: every live group ("1 host read, 1 SPMD step, D slices"); the
-        #: numpy emulation books the identical accounting.  Collection is
-        #: double-buffered against the next round's host decode (ROADMAP
-        #: mesh item (c)).
-        self.ragged = bool(ragged)
 
     def run(
         self,
@@ -905,8 +737,8 @@ class MeshLaneExecutor:
         stats: Optional[ExecStats] = None,
     ) -> Iterator[ExecResult]:
         """Single-program path (``VSWEngine.run``): the message array rides
-        as a 1-lane group; the lane backends reduce to the plain ones for a
-        single lane, so this is bitwise the single-device engine sweep."""
+        as a 1-lane group; a lane's row is bitwise a solo run, so this is
+        bitwise the single-device engine sweep."""
         groups: Sequence[GroupDispatch] = [(np.asarray(msgs)[None], combine)]
         for _, res in self.run_groups(loaded, groups, stats):
             yield ExecResult(res.shard_id, res.v0, res.v1, res.acc[0],
@@ -921,22 +753,6 @@ class MeshLaneExecutor:
         masked: bool = False,
         lanes_live: Optional[int] = None,
     ) -> Iterator[Tuple[int, ExecResult]]:
-        if self.ragged:
-            yield from self._run_groups_ragged(loaded, groups, stats,
-                                               masked, lanes_live)
-            return
-        n_dev = self.partition.n_dev
-        bufs: List[List[LoadedShard]] = [[] for _ in range(n_dev)]
-        for ls in loaded:
-            d = self.partition.device_of(ls.shard_id)
-            bufs[d].append(ls)
-            if len(bufs[d]) >= self.batch_shards:
-                yield from self._flush(bufs, groups, stats)
-                bufs = [[] for _ in range(n_dev)]
-        if any(bufs):
-            yield from self._flush(bufs, groups, stats)
-
-    def _run_groups_ragged(self, loaded, groups, stats, masked, lanes_live):
         """One SPMD step (or emulated round) per flush for ALL groups, with
         batch ``i``'s collect deferred until batch ``i+1``'s dispatch is in
         flight — the mesh double-buffer (DESIGN.md §14)."""
@@ -1087,61 +903,6 @@ class MeshLaneExecutor:
         if pending is not None:
             yield from collect(pending)
 
-    def _flush(self, bufs, groups, stats):
-        live = [(gi, ga) for gi, ga in enumerate(groups) if ga is not None]
-        if not live:
-            return
-        t0 = time.perf_counter()
-        results = []
-        with trace.span(
-            "exec.dispatch",
-            groups=len(live),
-            shards=sum(len(b) for b in bufs),
-            devices=sum(1 for b in bufs if b),
-            backend=self.backend_name,
-        ):
-            if self.backend_name == "numpy":
-                fn = LANE_BACKENDS["numpy"]
-                for gi, (msgs, combine) in live:
-                    for buf in bufs:
-                        for ls in buf:
-                            acc = np.asarray(fn(ls.csr, ls.ell, msgs, combine))
-                            results.append((gi, ls, acc, len(buf)))
-            else:
-                from repro.kernels.spmv_ell import ops as spmv_ops
-
-                accs_by_group, _ = spmv_ops.ell_update_lanes_mesh_multi(
-                    [[ls.ell for ls in buf] for buf in bufs],
-                    [ga[0] for _, ga in live],
-                    [ga[1] for _, ga in live],
-                    mesh=self.mesh, backend=self.backend_name,
-                )
-                for (gi, _), accs_dev in zip(live, accs_by_group):
-                    for buf, accs in zip(bufs, accs_dev):
-                        for ls, acc in zip(buf, accs):
-                            results.append((gi, ls, np.asarray(acc), len(buf)))
-        if stats is not None:
-            total = sum(len(b) for b in bufs)
-            # One SPMD launch per group covers every device's slice; the
-            # numpy emulation books the same way so accounting is
-            # backend-invariant (fig_mesh asserts conservation on it).
-            stats.dispatches += len(live)
-            stats.batches += 1
-            stats.shards_executed += total * len(live)
-            for d, buf in enumerate(bufs):
-                if buf:
-                    stats.device_shards[d] = (
-                        stats.device_shards.get(d, 0) + len(buf) * len(live)
-                    )
-                    stats.device_dispatches[d] = (
-                        stats.device_dispatches.get(d, 0) + len(live)
-                    )
-            stats.exec_s += time.perf_counter() - t0
-        for gi, ls, acc, bs in results:
-            ref = ls.ref
-            yield gi, ExecResult(ls.shard_id, ref.v0, ref.v1, acc,
-                                 batch_size=bs)
-
 
 def make_executor(backend: str, *, batch_shards: int = 1):
     """Pick the executor for a backend: batching only exists for the ELL
@@ -1153,16 +914,12 @@ def make_executor(backend: str, *, batch_shards: int = 1):
     return PerShardExecutor(backend)
 
 
-def make_lane_executor(backend: str, *, batch_shards: int = 1,
-                       ragged: bool = True):
-    """Executor whose dispatches carry a lane (concurrent-query) axis:
-    same selection rule as :func:`make_executor`, except that ``ragged``
-    (the RaggedFuse one-launch path, on by default) also wants the batched
-    executor at ``batch_shards=1`` — a ragged flush is still 1 launch where
-    the per-shard path would pay G."""
+def make_lane_executor(backend: str, *, batch_shards: int = 1):
+    """Executor whose dispatches carry a lane (concurrent-query) axis: the
+    ragged launch for the ELL backends at any ``batch_shards``, the
+    per-shard oracle for numpy."""
     if batch_shards < 1:
         raise ValueError("batch_shards must be >= 1")
-    if backend in _BATCHED_LANE_BACKENDS and (batch_shards > 1 or ragged):
-        return BatchedEllExecutor(backend, batch_shards, lanes=True,
-                                  ragged=ragged)
+    if backend in _RAGGED_LANE_BACKENDS:
+        return RaggedLaneExecutor(backend, batch_shards)
     return PerShardExecutor(backend, lanes=True)

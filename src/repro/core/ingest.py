@@ -98,7 +98,7 @@ def write_edge_file(
 ) -> int:
     """Write an edge file in ``chunk_edges`` slices; returns bytes written.
 
-    Exists so tests/benchmarks can materialize inputs without holding an
+    Exists so tests and examples can materialize inputs without holding an
     interleaved copy of the whole edge list.
     """
     if chunk_edges < 1:

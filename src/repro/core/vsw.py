@@ -226,8 +226,7 @@ class VSWEngine:
         )
         if self.partition is not None:
             self.executor = MeshLaneExecutor(
-                backend, self.partition, self.mesh,
-                batch_shards=batch_shards, lanes=False,
+                backend, self.partition, self.mesh, batch_shards=batch_shards
             )
         else:
             self.executor = make_executor(backend, batch_shards=batch_shards)

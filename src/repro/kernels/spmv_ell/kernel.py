@@ -25,11 +25,8 @@ These kernels compile for a v5e at TR=8, K=128, W=16384
 ``K == L`` there.  Off the TPU they run in the Pallas interpreter
 (:func:`repro.kernels.pallas_compiled`), where any K and W work.
 
-Three variants:
+Two variants:
 - ``masked``  (paper-faithful layout): validity carried as a bool tile.
-- ``sentinel``: invalid slots point at an identity-filled pad appended to
-  each window of the table — no mask tile at all, cutting streamed edge
-  bytes by the full mask plane.
 - ``ragged``: the masked kernel over many lanes (DESIGN.md §14).  The grid
   is ``(n_lanes / LB, n_tiles)``: one step holds one tile's index and mask
   blocks and an ``(LB, W / L, L)`` block of its lanes' windows, and splits
@@ -85,12 +82,6 @@ RAGGED_WINDOW_VMEM = 8 << 20
 def table_lanes(window: int) -> int:
     """Lanes per table row: 128 for any window that is a multiple of 128."""
     return math.gcd(window, 128)
-
-
-def sentinel_pad(window: int) -> int:
-    """Identity slots appended per window by the sentinel layout: one
-    ``(8, L)`` tile, so the extended table keeps 8-row alignment."""
-    return 8 * table_lanes(window)
 
 
 def _table(msgs: jax.Array, window: int) -> jax.Array:
@@ -382,7 +373,7 @@ def ell_partials_ragged(
     combines: tuple,  # deduplicated combine arms, static
 ) -> jax.Array:
     """Per-ELL-row partials for ALL lanes of ALL fusion groups, [n_lanes,
-    n_ell] — ONE launch where the multi path pays G (DESIGN.md §14).
+    n_ell], from ONE launch (DESIGN.md §14).
 
     The grid is ``(n_lanes / LB, n_tiles)`` with ``LB`` from
     :func:`ragged_lane_block`: one step holds one tile's index and mask
@@ -429,46 +420,3 @@ def ell_partials_ragged(
     return _over_tile_chunks(call, (tile_window, n_iter, tile_rows), tr,
                              ell_idx, ell_valid)
 
-
-# -------------------------------------------------------------- sentinel
-def _sentinel_kernel(combine: str, tile_window_ref, idx_ref, tab_ref, out_ref):
-    """No mask plane: padding slots index the identity pad of the table."""
-    (g,) = _gather([tab_ref], *_split(idx_ref, tab_ref.shape[-1]))
-    out_ref[...] = _reduce(g, combine)[None]
-
-
-@functools.partial(jax.jit, static_argnames=("window", "tr", "combine"))
-def ell_partials_sentinel(
-    ell_idx: jax.Array,  # [n_ell, K] indices into the EXTENDED window
-    tile_window: jax.Array,
-    msgs_ext: jax.Array,  # [num_windows * window] identity-padded windows
-    *,
-    window: int,  # EXTENDED window size (W + sentinel_pad(W))
-    tr: int,
-    combine: str,
-) -> jax.Array:
-    k = ell_idx.shape[1]
-    tab = _table(msgs_ext, window)
-    rows, lanes = window // tab.shape[-1], tab.shape[-1]
-
-    def call(tw, idx):
-        n_tiles = tw.shape[0]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_tiles,),
-            in_specs=[
-                pl.BlockSpec((tr, k), lambda i, tw: (i, 0)),
-                pl.BlockSpec((rows, lanes), lambda i, tw: (tw[i], 0)),
-            ],
-            out_specs=pl.BlockSpec((None, 1, tr), lambda i, tw: (i, 0, 0)),
-        )
-        out = pl.pallas_call(
-            functools.partial(_sentinel_kernel, combine),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n_tiles, 1, tr), msgs_ext.dtype),
-            name="ell_partials_sentinel",
-            interpret=not kernels.pallas_compiled(),
-        )(tw, idx, tab)
-        return out.reshape(n_tiles * tr)
-
-    return _over_tile_chunks(call, (tile_window,), tr, ell_idx)

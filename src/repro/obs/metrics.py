@@ -35,7 +35,7 @@ the core/serve/delta packages (which import *us* for tracing).
 Histograms are fixed log-bucket streaming estimators: ~7% bucket growth
 gives ≲3.5% relative quantile error at O(1) memory, enough for the
 p50/p95/p99 tail-latency numbers ``GraphService.metrics_snapshot()``
-surfaces into ``BENCH_graphmp.json``.
+reports.
 """
 
 from __future__ import annotations

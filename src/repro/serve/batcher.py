@@ -19,8 +19,8 @@ to ``max_groups``.  Everything else stays queued in order — no
 starvation: the oldest request always rides the next sweep.
 
 ``fuse_programs=False`` restores PR 2's key-equality batching (one group,
-identical program keys only) — the baseline the fusion benchmarks compare
-against.
+identical program keys only) — the baseline fused batching is compared
+against in ``tests/test_fusion.py``.
 
 Lane counts are padded to the next power of two (:func:`pad_lanes`) so
 the jit'd lane kernels see a bounded set of shapes — at most

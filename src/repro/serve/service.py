@@ -141,8 +141,8 @@ class GraphService:
 
     Mesh serving (DESIGN.md §10): pass ``mesh=`` through any factory — it
     flows to :class:`VSWEngine` with the other engine kwargs, and every
-    sweep the worker runs then dispatches per-group per-device slices
-    ("1 host read, G x D slices").  Results are bitwise those of the
+    sweep the worker runs then dispatches every group's per-device slices
+    in one SPMD step ("1 host read, 1 SPMD step, D slices").  Results are bitwise those of the
     single-device service; ``stats()["mesh_devices"]`` reports D."""
 
     def __init__(
@@ -159,7 +159,6 @@ class GraphService:
         auto_compact_runs: Optional[int] = None,
         max_groups: int = 2,
         fuse_programs: bool = True,
-        ragged: bool = True,
     ):
         self.engine = engine
         self.batcher = LaneBatcher(
@@ -171,9 +170,6 @@ class GraphService:
         self.max_pending = max_pending
         self.graph_version = graph_version
         self.lane_selective = lane_selective
-        # RaggedFuse (DESIGN.md §14): one ragged kernel launch per shard
-        # batch covers every fusion group (jnp/pallas lane executors).
-        self.ragged = ragged
         # Set by ``from_store(warm_state=...)``: the apply_warm_state
         # report (None = no warm restore was attempted).
         self.warm_restore_report: Optional[Dict[str, Any]] = None
@@ -248,7 +244,6 @@ class GraphService:
         "auto_compact_runs",
         "max_groups",
         "fuse_programs",
-        "ragged",
     )
 
     @classmethod
@@ -605,7 +600,6 @@ class GraphService:
             batch_shards=self.batch_shards,
             pad_pow2=self.batcher.pad_pow2,
             lane_selective=self.lane_selective,
-            ragged=self.ragged,
         )
         try:
             with trace.span(
@@ -667,9 +661,7 @@ class GraphService:
         per-query latency split into queue wait vs sweep time, per-sweep
         stage timings (load / exposed load wait / dispatch), and the
         outcome of replaying every conservation identity declared by the
-        sweeps ingested so far (empty list = all conserved).  The
-        benchmark harness writes the latency percentiles into consolidated
-        ``BENCH_graphmp.json`` rows.
+        sweeps ingested so far (empty list = all conserved).
 
         ``window=True`` (GraphPulse, DESIGN.md §13) reports each histogram
         block over the records since the PREVIOUS windowed snapshot

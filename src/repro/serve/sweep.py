@@ -117,9 +117,10 @@ class SweepIterStats:
     # fusion: program groups live this iteration (1 for plain lane sweeps)
     groups: int = 1
     # RaggedFuse (DESIGN.md §14): kernel dispatches and shard batches this
-    # iteration.  Ragged sweeps hold dispatches == batches (one launch per
-    # batch covers every group); the multi path pays groups x batches.
-    # Conservation: batches <= dispatches.
+    # iteration.  The ELL lane executors hold dispatches == batches (one
+    # launch per batch covers every group); the numpy oracle dispatches per
+    # shard per group and books no batches.  Conservation: batches <=
+    # dispatches.
     dispatches: int = 0
     batches: int = 0
     # double-buffer overlap: wall time launches stayed in flight while the
@@ -127,8 +128,8 @@ class SweepIterStats:
     overlap_s: float = 0.0
     # mesh sweeps (DESIGN.md §10); empty tuples on single-device sweeps.
     # Conserved like IterStats': sum(device_shards) == shards_processed,
-    # sum(device_bytes) == bytes_read — one host read per shard, sliced
-    # G x D ways, never re-read per device.
+    # sum(device_bytes) == bytes_read — one host read per shard, never
+    # re-read per device.
     device_shards: tuple = ()
     device_dispatches: tuple = ()
     device_bytes: tuple = ()
@@ -308,8 +309,8 @@ class FusedSweep:
     """Drive G program groups over ONE pinned shard stream.
 
     Each iteration plans the union active set across every group, loads
-    each planned shard once, and dispatches it per live group through the
-    lane executor's multi-group path — with per-(group, lane) masks under
+    each planned shard once, and dispatches it for every live group through
+    the lane executor's ``run_groups`` — with per-(group, lane) masks under
     lane-aware selective scheduling.
     """
 
@@ -320,7 +321,6 @@ class FusedSweep:
         batch_shards: int = 1,
         pad_pow2: bool = True,
         lane_selective: bool = True,
-        ragged: bool = True,
     ):
         self.engine = engine
         self.pad_pow2 = pad_pow2
@@ -330,24 +330,22 @@ class FusedSweep:
         # masked (the shard still loads once).  Same bitwise argument as
         # whole-shard skipping, per lane (DESIGN.md §6).
         self.lane_selective = lane_selective
-        # RaggedFuse (DESIGN.md §14): the jnp/pallas lane executors
-        # concatenate every live group along the lane axis and launch ONE
-        # ragged kernel per shard batch (instead of G), double-buffering
-        # collection against the next batch's decode.  Bitwise-identical
-        # per group; the numpy oracle always runs per-group.
-        self.ragged = ragged
+        # RaggedFuse (DESIGN.md §14): the jnp/pallas lane executor
+        # concatenates every live group along the lane axis and launches
+        # ONE ragged kernel per shard batch, double-buffering collection
+        # against the next batch's decode; the numpy oracle runs per group.
         # An engine booted with ``mesh=`` carries a MeshPartition: lane
         # dispatch then routes each decoded shard to its owning device and
-        # launches one SPMD program per flush — "1 host read, G x D
+        # launches one SPMD step per flush — "1 host read, 1 SPMD step, D
         # slices" (DESIGN.md §10).  Same run_groups surface either way.
         if getattr(engine, "partition", None) is not None:
             self.executor = MeshLaneExecutor(
                 engine.backend_name, engine.partition, engine.mesh,
-                batch_shards=batch_shards, lanes=True, ragged=ragged,
+                batch_shards=batch_shards,
             )
         else:
             self.executor = make_lane_executor(
-                engine.backend_name, batch_shards=batch_shards, ragged=ragged
+                engine.backend_name, batch_shards=batch_shards
             )
         self.iter_stats: List[SweepIterStats] = []
 
@@ -710,7 +708,6 @@ class LaneSweep:
         batch_shards: int = 1,
         pad_pow2: bool = True,
         lane_selective: bool = True,
-        ragged: bool = True,
     ):
         self.engine = engine
         self.program = program
@@ -719,7 +716,6 @@ class LaneSweep:
             batch_shards=batch_shards,
             pad_pow2=pad_pow2,
             lane_selective=lane_selective,
-            ragged=ragged,
         )
 
     @property

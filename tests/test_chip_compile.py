@@ -73,18 +73,6 @@ def test_masked_compiles_for_v5e(one_chip):
     _check(c)
 
 
-def test_sentinel_compiles_for_v5e(one_chip):
-    from repro.kernels.spmv_ell import kernel as Kn
-
-    ext = W + Kn.sentinel_pad(W)
-    c = Kn.ell_partials_sentinel.lower(
-        one_chip((N_ELL, K), jnp.int32), one_chip((N_ELL // TR,), jnp.int32),
-        one_chip((N_WINDOWS * ext,), jnp.float32),
-        window=ext, tr=TR, combine="max",
-    ).compile()
-    _check(c)
-
-
 @pytest.mark.parametrize("lanes,n_ell,n_windows,lane_blocks", [
     (24, 32768, 8, 1),   # the serving cell's launch: one block of 24 lanes
     (96, N_ELL, N_WINDOWS, 2),  # past the VMEM budget: two blocks of 48
@@ -109,6 +97,25 @@ def test_ragged_compiles_for_v5e(one_chip, lanes, n_ell, n_windows,
     table = jax.jit(Kn.ragged_row_table, static_argnames=("window", "tr"))
     c = table.lower(*ell, window=W, tr=TR).compile()
     assert [o.shape for o in c.out_info] == [(n_tiles,), (n_tiles, rows)]
+
+
+def test_ragged_launch_compiles_for_v5e(one_chip):
+    """The whole one-chip ragged launch at the serving cell's shape (24
+    lanes, 32,768 ELL rows over eight windows, 16 shards of 8,192 rows):
+    the row-list prologue, the kernel and the per-arm segment combine."""
+    from repro.kernels.spmv_ell import ops
+
+    lanes, n_ell, n_windows, rows = 24, 32768, 8, 8192
+    c = ops._update_lanes_ragged_jit.lower(
+        one_chip((n_ell, K), jnp.int16), one_chip((n_ell, K), jnp.bool_),
+        one_chip((n_ell,), jnp.int32),  # seg
+        one_chip((n_ell // TR,), jnp.int32),  # tile_window
+        one_chip((lanes,), jnp.int32),  # combine ids
+        one_chip((lanes, n_windows * W), jnp.float32),
+        window=W, tr=TR, rows=rows, combines=COMBINES,
+    ).compile()
+    _check(c)
+    assert c.out_info.shape == (lanes, rows)
 
 
 def test_mesh_ragged_step_compiles_for_v5e_2x2(topo, tpu_kernels):

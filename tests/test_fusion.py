@@ -459,10 +459,11 @@ def test_run_groups_matches_per_group_run():
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
 def test_batched_run_groups_matches_per_group_run_e2e(backend):
-    """BatchedEllExecutor.run_groups (one concat, G dispatches) must be
-    bitwise the per-group batched dispatch."""
+    """The lane executor's run_groups (one concat, one ragged launch for
+    every group) must be bitwise each group's own launch, and book one
+    dispatch per batch."""
     from repro.core.csr import csr_to_ell
-    from repro.core.executor import make_lane_executor
+    from repro.core.executor import ExecStats, make_lane_executor
     from repro.core.pipeline import LoadedShard
 
     g = rmat_graph(250, 3000, seed=71)
@@ -474,9 +475,12 @@ def test_batched_run_groups_matches_per_group_run_e2e(backend):
     msgs_a = rng.random((2, meta.num_vertices)).astype(np.float32)
     msgs_b = rng.random((4, meta.num_vertices)).astype(np.float32)
     ex = make_lane_executor(backend, batch_shards=3)
+    stats = ExecStats()
     got = {}
-    for gi, res in ex.run_groups(loaded, [(msgs_a, "sum"), (msgs_b, "min")]):
+    for gi, res in ex.run_groups(loaded, [(msgs_a, "sum"), (msgs_b, "min")],
+                                 stats):
         got.setdefault(gi, []).append(res)
+    assert stats.dispatches == stats.batches == 2  # 4 shards, 3 a batch
     for gi, msgs, combine in ((0, msgs_a, "sum"), (1, msgs_b, "min")):
         solo = list(ex.run(loaded, msgs, combine))
         for a, b in zip(got[gi], solo):

@@ -22,15 +22,22 @@ from repro.kernels.spmv_ell import ops as spmv_ops
 # ----------------------------------------------------------------- spmv_ell
 @pytest.mark.parametrize("window,k,tr", [(256, 8, 8), (512, 32, 8), (1024, 128, 8)])
 @pytest.mark.parametrize("combine", ["sum", "min", "max"])
-@pytest.mark.parametrize("variant", ["masked", "sentinel"])
-def test_spmv_ell_matches_oracle(window, k, tr, combine, variant):
+@pytest.mark.parametrize("path", ["masked", "ragged"])
+def test_spmv_ell_matches_oracle(window, k, tr, combine, path):
+    """The single-query masked update and a one-group, one-lane ragged
+    launch each match the scatter-reduce oracle."""
     g = rmat_graph(1500, 20000, seed=42)
     meta, shards = preprocess(g, num_shards=3)
     msgs = np.random.default_rng(0).random(g.num_vertices).astype(np.float32)
     for s in shards:
         e = csr_to_ell(s, g.num_vertices, window=window, k=k, tr=tr)
         oracle = update_shard_numpy(s, None, msgs.astype(np.float64), combine)
-        acc = np.asarray(spmv_ops.ell_update(e, msgs, combine, variant=variant))
+        if path == "masked":
+            acc = np.asarray(spmv_ops.ell_update(e, msgs, combine))
+        else:
+            ((acc,),) = spmv_ops.ell_update_lanes_ragged(
+                [e], [msgs[None]], [combine])
+            acc = acc[0]
         a = np.nan_to_num(acc, posinf=1e30, neginf=-1e30)
         b = np.nan_to_num(oracle, posinf=1e30, neginf=-1e30)
         assert np.allclose(a, b, rtol=1e-4, atol=1e-5), (s.shard_id, combine)

@@ -1,28 +1,28 @@
 """RaggedFuse: one ragged kernel launch per shard batch covering ALL
 fusion groups (DESIGN.md §14).
 
-The ragged contract, tested four ways:
+The ragged contract — each lane's row is bitwise a solo run of that
+lane's program on the masked path — tested four ways:
 
 1. **Padding algebra** — :func:`ragged_lane_pad` never wastes more lanes
-   than the per-group power-of-two padding the multi-launch path pays,
-   and :func:`ragged_lane_concat` lays groups out contiguously with
-   per-lane combine-arm ids (padding lanes carry an id matching NO arm).
-2. **Bitwise kernels** — ``ell_update_lanes_ragged`` equals
-   ``ell_update_lanes_multi`` bit-for-bit per group across combine mixes
+   than padding each group to a power of two, and
+   :func:`ragged_lane_concat` lays groups out contiguously with per-lane
+   combine-arm ids (padding lanes carry an id matching NO arm).
+2. **Bitwise kernels** — ``ell_update_lanes_ragged`` equals a solo
+   ``ell_update`` of each lane bit-for-bit across combine mixes
    (including duplicated monoids sharing one arm and inf-heavy min
-   inputs), and the mesh variant equals the mesh multi path at D ∈
+   inputs), and the mesh step equals the single-device service at D ∈
    {1, 2, 8} (numpy emulation inline; jnp/pallas in a subprocess under
    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  The kernel's
    per-tile row lists change no partial: hand-built tiles (no valid slot,
    one row, every row, several slots on a row, invalid slots on listed
    rows) equal the masked kernel and the full schedule, and the device
    prologue's lists equal ``np.unique`` per tile.
-3. **Bitwise sweeps** — a ``FusedSweep(ragged=True)`` reproduces the
-   ``ragged=False`` multi-path results exactly through masked groups
-   (lane-selective scheduling), mid-sweep retirement and backfill.
-4. **Conserved accounting** — a ragged sweep books exactly ONE dispatch
-   per flushed batch (``dispatches == batches``) where the multi path
-   pays ``groups`` per batch, and the declared identities
+3. **Bitwise sweeps** — a ``FusedSweep`` reproduces each query's solo
+   engine run exactly through masked groups (lane-selective scheduling),
+   mid-sweep retirement and backfill.
+4. **Conserved accounting** — a sweep books exactly ONE dispatch per
+   flushed batch (``dispatches == batches``), and the declared identities
    (``ragged_dispatches <= batches <= dispatches``,
    ``sum(group_lanes) == ragged_lanes``) replay clean through
    ``MetricsRegistry.verify_conservation``.
@@ -81,7 +81,8 @@ def _solo(eng, program, source, max_iters):
 # ------------------------------------------------------- padding algebra
 def test_ragged_lane_pad_never_worse_than_per_group_pow2():
     """Property (seeded): for ANY group lane counts, the single ragged
-    launch's padding waste <= the multi path's per-group pow2 waste."""
+    launch's padding waste <= the waste of padding each group to a power
+    of two."""
     rng = np.random.default_rng(140)
     for _ in range(300):
         counts = rng.integers(0, 33, size=rng.integers(1, 7)).tolist()
@@ -127,9 +128,11 @@ def test_ragged_lane_concat_layout_and_arm_dedup():
     ("sum", "min", "max"),
     ("min", "sum"),
     ("sum", "min", "sum"),   # duplicated monoid -> shared arm
-    ("min",),                # single group: ragged degenerates to multi
+    ("min",),                # single group
 ])
-def test_ragged_ops_bitwise_vs_multi_e2e(combines):
+def test_ragged_ops_bitwise_vs_solo_e2e(combines):
+    """Each lane of a ragged launch over three shards equals a solo masked
+    ``ell_update`` of that lane on its shard, bit for bit."""
     from repro.kernels.spmv_ell import ops as spmv_ops
 
     g = rmat_graph(600, 7000, seed=142)
@@ -145,14 +148,16 @@ def test_ragged_ops_bitwise_vs_multi_e2e(combines):
             # in-kernel arm selection exactly as it does solo
             m[m > 0.6] = np.inf if c == "min" else -np.inf
         msgs_by_group.append(m)
-    ref = spmv_ops.ell_update_lanes_multi(ells, msgs_by_group, list(combines))
     out = spmv_ops.ell_update_lanes_ragged(ells, msgs_by_group, list(combines))
-    assert len(out) == len(ref) == len(combines)
-    for gi, (accs_r, accs_m) in enumerate(zip(out, ref)):
-        assert len(accs_r) == len(accs_m) == len(ells)
-        for si, (a, b) in enumerate(zip(accs_r, accs_m)):
-            assert a.shape == b.shape
-            assert np.array_equal(_norm(a), _norm(b)), (gi, si)
+    assert len(out) == len(combines)
+    for gi, (accs, msgs, c) in enumerate(zip(out, msgs_by_group, combines)):
+        assert len(accs) == len(ells)
+        for si, (a, e) in enumerate(zip(accs, ells)):
+            assert a.shape == (msgs.shape[0], e.rows)
+            for lane in range(msgs.shape[0]):
+                solo = np.asarray(spmv_ops.ell_update(e, msgs[lane], c))
+                assert np.array_equal(_norm(a[lane]), _norm(solo)), \
+                    (gi, si, lane)
     # empty shard list: shape-compatible empty result
     assert spmv_ops.ell_update_lanes_ragged([], msgs_by_group,
                                             list(combines)) == \
@@ -371,11 +376,11 @@ def test_ragged_row_table_matches_host_helper_e2e(case):
     ("jnp", 1, True), ("jnp", 3, True), ("pallas", 2, True),
     ("jnp", 2, False),
 ])
-def test_ragged_sweep_bitwise_vs_multi_e2e(tmp_path, backend, batch_shards,
-                                           lane_selective):
-    """FusedSweep(ragged=True) == FusedSweep(ragged=False) bitwise per
-    lane through masked groups and mid-sweep retirement/backfill — and
-    the ragged run books ONE dispatch per batch where multi pays G."""
+def test_ragged_sweep_bitwise_vs_solo_e2e(tmp_path, backend, batch_shards,
+                                          lane_selective):
+    """A FusedSweep's lanes equal each query's solo engine run bitwise
+    through masked groups and mid-sweep retirement/backfill — and the
+    sweep books ONE dispatch per batch for all its groups."""
     g = rmat_graph(400, 4500, seed=143)
     eng = _mk_engine(tmp_path, f"e{backend}{batch_shards}", g, num_shards=5,
                      backend=backend, batch_shards=batch_shards)
@@ -401,52 +406,45 @@ def test_ragged_sweep_bitwise_vs_multi_e2e(tmp_path, backend, batch_shards,
             return out
         return backfill
 
-    runs = {}
-    for ragged in (True, False):
-        sweep = FusedSweep(eng, batch_shards=batch_shards,
-                           lane_selective=lane_selective, ragged=ragged)
-        q = list(queue)
-        res = sweep.run(mk_seeds(), backfill=mk_backfill(q))
-        runs[ragged] = ({r.token: r for r in res}, sweep.iter_stats)
-    by_r, stats_r = runs[True]
-    by_m, stats_m = runs[False]
-    assert set(by_r) == set(by_m) == {"b0", "s0", "p0", "p1", "b2"}
-    for tok in by_m:
-        assert np.array_equal(_norm(by_r[tok].values),
-                              _norm(by_m[tok].values)), tok
-        assert by_r[tok].iterations == by_m[tok].iterations
-        assert by_r[tok].converged == by_m[tok].converged
-    # accounting: ragged == one launch per flushed batch, every iteration
-    assert sum(s.dispatches for s in stats_r) > 0
-    for s in stats_r:
+    sweep = FusedSweep(eng, batch_shards=batch_shards,
+                       lane_selective=lane_selective)
+    res = sweep.run(mk_seeds(), backfill=mk_backfill(list(queue)))
+    by_tok = {r.token: r for r in res}
+    solo = {"b0": ("bfs", 0, 3), "s0": ("sssp", 3, 12), "p0": ("ppr", 5, 8),
+            "p1": ("ppr", 11, 2), "b2": ("bfs", 9, 12)}
+    assert set(by_tok) == set(solo)
+    for tok, (prog, src, iters) in solo.items():
+        ref = _solo(eng, prog, src, iters)
+        assert np.array_equal(_norm(by_tok[tok].values),
+                              _norm(ref.values)), tok
+        assert by_tok[tok].iterations == ref.num_iterations, tok
+        assert by_tok[tok].converged == ref.converged, tok
+    # accounting: one launch per flushed batch, every iteration
+    assert sum(s.dispatches for s in sweep.iter_stats) > 0
+    for s in sweep.iter_stats:
         assert s.dispatches == s.batches, s
         assert s.overlap_s >= 0.0
-    # the multi path pays per live group: strictly more launches overall
-    assert sum(s.dispatches for s in stats_m) > \
-        sum(s.dispatches for s in stats_r)
-    if batch_shards > 1:  # batch_shards=1 multi runs per-shard (no batches)
-        assert sum(s.batches for s in stats_m) == \
-            sum(s.batches for s in stats_r)
     eng.close()
 
 
 def test_ragged_service_mixed_workload_bitwise_e2e(tmp_path):
-    """Service-level: ragged on (default) vs off, mixed-algebra workload
-    with lane retirement — every query bitwise-equal to its solo run."""
+    """Service-level: a mixed-algebra workload with lane retirement, one
+    shard and two to a launch — every query bitwise-equal to its solo
+    run."""
     g = rmat_graph(300, 3500, seed=144)
     eng = _mk_engine(tmp_path, "ref", g, num_shards=5, backend="jnp")
     refs = {c: _solo(eng, *c, 12) for c in MIXED}
     eng.close()
-    for ragged in (True, False):
-        svc = _mk_service(tmp_path, f"svc{ragged}", g, num_shards=5,
+    for batch_shards in (1, 2):
+        svc = _mk_service(tmp_path, f"svc{batch_shards}", g, num_shards=5,
                           backend="jnp", max_lanes=8, max_groups=2,
-                          batch_shards=2, ragged=ragged)
+                          batch_shards=batch_shards)
         with svc.submit_batch():
             futs = [svc.submit(p, s, max_iters=12) for p, s in MIXED]
         for c, f in zip(MIXED, futs):
             qr = f.result(timeout=240)
             assert np.array_equal(_norm(qr.values),
-                                  _norm(refs[c].values)), (ragged, c)
+                                  _norm(refs[c].values)), (batch_shards, c)
         # futures resolve inside the sweep; the counter bumps at sweep end
         deadline = time.monotonic() + 30
         while svc.stats()["sweeps"] < 1 and time.monotonic() < deadline:
@@ -464,7 +462,7 @@ def test_ragged_mesh_numpy_emulation_bitwise(tmp_path, D):
     eng = _mk_engine(tmp_path, f"m{D}", g, backend="numpy", mesh=D)
     ref = _mk_engine(tmp_path, "mref", g, backend="numpy")
     bfs, ppr = apps.lane_bfs(), apps.lane_ppr()
-    sweep = FusedSweep(eng, ragged=True)
+    sweep = FusedSweep(eng)
     res = sweep.run([
         [LaneSeed(source=2, max_iters=10, token="b", program=bfs)],
         [LaneSeed(source=7, max_iters=6, token="p", program=ppr)],
@@ -498,15 +496,14 @@ _MESH_RAGGED_SCRIPT = textwrap.dedent(
         for backend in ("jnp", "pallas"):
             solo = GraphService.from_graph(
                 g, d + f"/solo{backend}", num_shards=6, window=128, k=16,
-                backend=backend, max_lanes=8, max_groups=2, batch_shards=2,
-                ragged=False)
+                backend=backend, max_lanes=8, max_groups=2, batch_shards=2)
             refs = {c: solo.query(*c, max_iters=12).values for c in cases}
             solo.close()
             for D in (1, 2, 8):
                 svc = GraphService.from_graph(
                     g, d + f"/{backend}{D}", num_shards=6, window=128,
                     k=16, backend=backend, max_lanes=8, max_groups=2,
-                    batch_shards=2, mesh=D, ragged=True)
+                    batch_shards=2, mesh=D)
                 with svc.submit_batch():
                     futs = [svc.submit(p, s, max_iters=12)
                             for p, s in cases]
@@ -546,7 +543,7 @@ def test_ragged_metrics_conservation_e2e(tmp_path):
     g = rmat_graph(250, 2500, seed=147)
     eng = _mk_engine(tmp_path, "cons", g, backend="jnp", batch_shards=2)
     bfs, ppr = apps.lane_bfs(), apps.lane_ppr()
-    sweep = FusedSweep(eng, batch_shards=2, ragged=True)
+    sweep = FusedSweep(eng, batch_shards=2)
     sweep.run([
         [LaneSeed(source=0, max_iters=8, token="b", program=bfs)],
         [LaneSeed(source=1, max_iters=8, token="p", program=ppr)],

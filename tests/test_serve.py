@@ -19,7 +19,6 @@ from repro.core.executor import (
     update_shard_numpy,
     update_shard_numpy_lanes,
     update_shard_jnp,
-    update_shard_jnp_lanes,
 )
 from repro.core.graph import chain_graph, rmat_graph
 from repro.core.sharding import preprocess
@@ -55,19 +54,25 @@ def _mk_engine(tmp_path, tag, g, **kw):
     return VSWEngine.from_graph(g, str(tmp_path / tag), **kw)
 
 
-# --------------------------------------------------- per-shard lane backends
+# ------------------------------------------------------------ lane backends
 def test_lane_backend_rows_are_bitwise_single_lane():
+    """Each row of the numpy lane oracle and of a one-group jnp ragged
+    launch is bitwise that lane's single-query update."""
     g = rmat_graph(300, 4000, seed=40)
     meta, shards = preprocess(g, num_shards=3)
     rng = np.random.default_rng(1)
     msgs = rng.random((4, meta.num_vertices)).astype(np.float32)
     from repro.core.csr import csr_to_ell
+    from repro.core.pipeline import LoadedShard
 
+    ex = make_lane_executor("jnp")
     for combine in ("sum", "min", "max"):
         for s in shards:
             lanes_np = update_shard_numpy_lanes(s, None, msgs, combine)
             ell = csr_to_ell(s, meta.num_vertices, window=64, k=8, tr=8)
-            lanes_jnp = update_shard_jnp_lanes(s, ell, msgs, combine)
+            (res,) = ex.run([LoadedShard(s.shard_id, s, ell)], msgs, combine)
+            lanes_jnp = res.acc
+            assert lanes_jnp.shape == (4, ell.rows)
             for l in range(4):
                 assert np.array_equal(
                     lanes_np[l], update_shard_numpy(s, None, msgs[l], combine)
@@ -78,14 +83,22 @@ def test_lane_backend_rows_are_bitwise_single_lane():
 
 
 def test_make_lane_executor_selection():
-    from repro.core.executor import BatchedEllExecutor, PerShardExecutor
+    """numpy lanes run the per-shard oracle; the ELL backends run the
+    ragged launch at every batch size."""
+    from repro.core.executor import PerShardExecutor, RaggedLaneExecutor
 
-    assert isinstance(make_lane_executor("numpy", batch_shards=4),
-                      PerShardExecutor)
-    ex = make_lane_executor("pallas", batch_shards=2)
-    assert isinstance(ex, BatchedEllExecutor) and ex.lanes
+    for bs in (1, 4):
+        ex = make_lane_executor("numpy", batch_shards=bs)
+        assert isinstance(ex, PerShardExecutor) and ex.lanes
+    for backend in ("jnp", "pallas"):
+        for bs in (1, 2):
+            ex = make_lane_executor(backend, batch_shards=bs)
+            assert isinstance(ex, RaggedLaneExecutor)
+            assert ex.batch_shards == bs
     with pytest.raises(ValueError):
         make_lane_executor("nope")
+    with pytest.raises(ValueError):
+        PerShardExecutor("jnp", lanes=True)  # no per-shard ELL lane route
 
 
 # ----------------------------------------------- bitwise oracle equivalence
